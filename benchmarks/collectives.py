@@ -1,13 +1,19 @@
-"""Benchmark: §3.3.2 — TAB one-shot vs NVLink-ring collectives, measured on
-a real (host-device) mesh.
+"""Benchmark: §3.3.2 — TAB one-shot vs NVLink-ring collectives, as HLO
+op counts on a virtual 8-device CPU mesh.
 
 Demonstrates Enabler 1 structurally: the ring allreduce lowers to 2(N-1)
 collective-permute steps in the HLO while the TAB schedule is a single
-all-reduce/psum op.  Wall-clock on forced CPU devices is not a performance
-claim; the HLO op counts are the reproducible artifact.
+all-reduce/psum op.  The mesh is 8 forced host devices of the CPU
+backend, so the microseconds in the rows time XLA:CPU and are not a
+performance claim; the op counts are the reproducible artifact.
 
-Run standalone (needs 8 host devices):
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+``run()`` re-executes this module in a child process pinned to the CPU
+(``JAX_PLATFORMS=cpu``): the parent has usually loaded JAX already, and
+on a TPU host a child reaching for the chip would contend with it.  A
+failed child raises.
+
+Run standalone:
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       PYTHONPATH=src python -m benchmarks.collectives
 """
 from __future__ import annotations
@@ -64,8 +70,9 @@ def _inner() -> list[str]:
 def run() -> list[str]:
     if os.environ.get("REPRO_COLLECTIVES_INNER") == "1":
         return _inner()
-    # re-exec with 8 host devices
+    # re-exec on 8 virtual CPU devices
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=8").strip()
     env["REPRO_COLLECTIVES_INNER"] = "1"
@@ -75,9 +82,9 @@ def run() -> list[str]:
         capture_output=True, text=True, env=env, timeout=600,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     rows = [l for l in out.stdout.splitlines() if l.startswith("collective")]
-    if not rows:
-        rows = [f"collective_allreduce,0,SUBPROCESS_FAILED: "
-                f"{out.stderr[-200:]}"]
+    if out.returncode != 0 or not rows:
+        raise RuntimeError(f"collectives child failed (rc "
+                           f"{out.returncode}): {out.stderr[-2000:]}")
     return rows
 
 

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, build_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.runtime import optim
 from repro.runtime.serve import BatchedServer
@@ -21,6 +22,7 @@ from repro.runtime.train import TrainConfig, make_train_step
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-14b", choices=list(ARCH_IDS))
     ap.add_argument("--steps", type=int, default=10)

@@ -23,6 +23,7 @@ import numpy as np
 
 from repro import memory
 from repro.configs import get_config, build_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.serve import BatchedServer
 
 PROMPTS = [
@@ -59,6 +60,7 @@ def serve_all(model, params, tag, paged=None, batch_size=2,
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("qwen2.5-14b").reduced(num_layers=8)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

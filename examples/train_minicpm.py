@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, build_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data.pipeline import DataConfig, PrefetchingLoader, SyntheticLM
 from repro.runtime import optim
 from repro.runtime.ft import FTConfig, FaultTolerantLoop
@@ -24,6 +25,7 @@ from repro.runtime.train import TrainConfig, make_train_step
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--d-model", type=int, default=384)

@@ -30,11 +30,7 @@ Schedule = Literal["tab", "ring"]
 
 
 def _axis_size(axis_name: str) -> int:
-    try:
-        return lax.axis_size(axis_name)
-    except AttributeError:          # jax < 0.5
-        frame = jax.core.axis_frame(axis_name)
-        return frame if isinstance(frame, int) else frame.size
+    return lax.axis_size(axis_name)
 
 
 # ---------------------------------------------------------------------------

@@ -5,10 +5,11 @@ Two halves of the FengHuang block-pool KV cache:
 * :func:`attend` / :func:`attend_ref` — the kernel-side read path.  The
   Pallas kernel (scalar-prefetched page tables) serves TPU; the gather
   oracle is the jittable fallback everywhere else.  Pick once per backend
-  with :func:`use_pallas_kernel`.
+  with :func:`use_pallas_kernel`; under a mesh the kernel runs per head
+  shard (:func:`sharded_paged_attention`).
 * :class:`BlockManager` — the host-side allocator.  It owns ONLY the
   bookkeeping (free list, per-slot page lists, lengths, accounting); the
-  stacked ``(L, P, page, Hkv, hd)`` device pools live in the serving
+  stacked ``(L, P, Hkv, page, hd)`` device pools live in the serving
   cache and are donated through every dispatch, with all KV writes done
   on device as batched scatters (one per decode step covering every
   layer and slot, one per prefill covering the whole prompt chunk).
@@ -32,15 +33,12 @@ from repro.kernels.paged_attention.ref import (gather_pages, gather_scales,
 # Logical specs for the block pool under tensor parallelism: the KV-heads
 # axis is "model"-sharded (each device owns its head shard of EVERY
 # page), page ids and per-slot tables are replicated host bookkeeping.
-# Quantized pools carry per-(token-slot, head) dequant scales that shard
+# Quantized pools carry per-(head, token-slot) dequant scales that shard
 # exactly like their pages (head axis on "model").
-POOL_SPEC = P(None, None, "model", None)                 # (P, page, Hkv, hd)
-STACKED_POOL_SPEC = P(None, None, None, "model", None)   # (L, P, ...)
-SCALE_SPEC = P(None, None, "model")                      # (P, page, Hkv)
-STACKED_SCALE_SPEC = P(None, None, None, "model")        # (L, P, page, Hkv)
+STACKED_POOL_SPEC = P(None, None, "model", None, None)   # (L, P, Hkv, pg, hd)
+STACKED_SCALE_SPEC = P(None, None, "model", None)        # (L, P, Hkv, pg)
 GATHERED_KV_SPEC = P(None, "model", None, None)          # (B, Hkv, n*pg, hd)
 GATHERED_SCALE_SPEC = P(None, "model", None)             # (B, Hkv, n*pg)
-PAGE_TABLE_SPEC = P()                                    # replicated
 
 
 def gather_pages_sharded(pages: jax.Array, page_table: jax.Array) -> jax.Array:
@@ -68,10 +66,7 @@ def use_pallas_kernel() -> bool:
     """Backend selection for the serving hot path, resolved once: the
     Mosaic kernel needs a TPU; everywhere else the gather-based oracle is
     the jittable (and bit-compatible) implementation."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - no backend at all
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -85,6 +80,47 @@ def attend(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     return paged_attention(q, k_pages, v_pages, page_table, seq_lens,
                            extra_kv=extra_kv, k_scales=k_scales,
                            v_scales=v_scales, interpret=interpret)
+
+
+def sharded_paged_attention(q: jax.Array, k_pages: jax.Array,
+                            v_pages: jax.Array, page_table: jax.Array,
+                            seq_lens: jax.Array, *,
+                            extra_kv: tuple[jax.Array, jax.Array] | None = None,
+                            k_scales: jax.Array | None = None,
+                            v_scales: jax.Array | None = None) -> jax.Array:
+    """:func:`paged_attention` under the ambient mesh.
+
+    XLA cannot partition a ``pallas_call``, so under a mesh each device
+    runs the kernel on its own KV-head shard inside ``jax.shard_map``
+    over the ``"model"`` axis: q, pools, scales and ``extra_kv`` enter
+    split by KV head, page table and lengths replicated — every head's
+    attention is local to the device holding its pages.  Outside a mesh
+    (or on a one-device mesh) the kernel is called directly."""
+    from repro.runtime.sharding import ambient_mesh, mesh_axis_sizes
+    arrays = {"q": q, "k_pages": k_pages, "v_pages": v_pages,
+              "page_table": page_table, "seq_lens": seq_lens}
+    if extra_kv is not None:
+        arrays["k0"], arrays["v0"] = extra_kv
+    if k_scales is not None:
+        arrays["k_scales"], arrays["v_scales"] = k_scales, v_scales
+
+    def call(a):
+        kv0 = (a["k0"], a["v0"]) if "k0" in a else None
+        return paged_attention(a["q"], a["k_pages"], a["v_pages"],
+                               a["page_table"], a["seq_lens"], extra_kv=kv0,
+                               k_scales=a.get("k_scales"),
+                               v_scales=a.get("v_scales"))
+
+    mesh = ambient_mesh()
+    if mesh is None or mesh_axis_sizes(mesh).get("model", 1) == 1:
+        return call(arrays)
+    # the head axis is dim 1 of every split operand: q/out (B, Hkv, G, d),
+    # pools (P, Hkv, page, d), scales (P, Hkv, page), extra (B, Hkv, d)
+    specs = {k: (P() if k in ("page_table", "seq_lens")
+                 else P(None, "model")) for k in arrays}
+    return jax.shard_map(call, mesh=mesh, in_specs=(specs,),
+                         out_specs=P(None, "model"),
+                         check_vma=False)(arrays)
 
 
 def attend_ref(q, k_pages, v_pages, page_table, seq_lens, extra_kv=None,

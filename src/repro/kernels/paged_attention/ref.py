@@ -69,28 +69,28 @@ def take_pages(pool: jax.Array, page_table: jax.Array) -> jax.Array:
 def gather_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:
     """Materialize the per-sequence view of a page pool.
 
-    pages: (P, page, Hkv, d); page_table: (B, n_pages) int32.
+    pages: (P, Hkv, page, d); page_table: (B, n_pages) int32.
     Returns (B, Hkv, n_pages * page, d) — the cache layout
     :func:`repro.models.layers.decode_attention` expects, with gathered
     position ``i`` holding absolute position ``i`` (pages are in order).
     """
     b, n_pages = page_table.shape
-    page, hkv, d = pages.shape[1:]
-    g = take_pages(pages, page_table)       # (B, n_pages, page, Hkv, d)
-    return g.reshape(b, n_pages * page, hkv, d).transpose(0, 2, 1, 3)
+    hkv, page, d = pages.shape[1:]
+    g = take_pages(pages, page_table)       # (B, n_pages, Hkv, page, d)
+    return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, n_pages * page, d)
 
 
 def gather_scales(scales: jax.Array, page_table: jax.Array) -> jax.Array:
     """Materialize the per-sequence view of a per-page scale array.
 
-    scales: (P, page, Hkv) — one dequant scale per (token slot, head);
+    scales: (P, Hkv, page) — one dequant scale per (head, token slot);
     page_table: (B, n_pages) int32.  Returns (B, Hkv, n_pages * page),
     aligned position-for-position with :func:`gather_pages`.
     """
     b, n_pages = page_table.shape
-    page, hkv = scales.shape[1:]
-    g = scales[page_table]                  # (B, n_pages, page, Hkv)
-    return g.reshape(b, n_pages * page, hkv).transpose(0, 2, 1)
+    hkv, page = scales.shape[1:]
+    g = scales[page_table]                  # (B, n_pages, Hkv, page)
+    return g.transpose(0, 2, 1, 3).reshape(b, hkv, n_pages * page)
 
 
 def paged_attention_ref(q, k_pages, v_pages, page_table, seq_lens,
@@ -98,13 +98,13 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, seq_lens,
     """Decode attention over a paged KV cache.
 
     q:          (B, Hkv, G, d)       one query token, grouped heads
-    k_pages:    (P, page, Hkv, d)    global page pool
-    v_pages:    (P, page, Hkv, d)
+    k_pages:    (P, Hkv, page, d)    global page pool
+    v_pages:    (P, Hkv, page, d)
     page_table: (B, pages_per_seq)   int32 page ids
     seq_lens:   (B,)                 valid tokens per sequence
     extra_kv:   optional current-token (k0, v0), each (B, Hkv, d),
                 attended as one extra column past the pooled positions
-    k_scales:   optional (P, page, Hkv) dequant scales for a quantized
+    k_scales:   optional (P, Hkv, page) dequant scales for a quantized
                 pool — multiplied into the fp32 view inline, so the
                 full-precision KV never materializes outside this gather
     v_scales:   same, for the value pool
@@ -112,27 +112,25 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, seq_lens,
     """
     b, hkv, g, d = q.shape
     pages_per_seq = page_table.shape[1]
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
 
     # fp8 pools gather as a uint8 bit-view and dequantize through the
     # 256-entry convert LUT (bit-identical; see take_pages / to_f32) —
     # the fix for the fp8 serving throughput cliff
-    k = take_pages(k_pages, page_table)        # (B, pages, page, Hkv, d)
-    v = take_pages(v_pages, page_table)
-    k = k.reshape(b, pages_per_seq * page, hkv, d)
-    v = v.reshape(b, pages_per_seq * page, hkv, d)
+    k = gather_pages(k_pages, page_table)      # (B, Hkv, S, d)
+    v = gather_pages(v_pages, page_table)
     if k_scales is not None:
-        ks = k_scales[page_table].reshape(b, pages_per_seq * page, hkv)
+        ks = gather_scales(k_scales, page_table)
         k = to_f32(k) * ks.astype(jnp.float32)[..., None]
     elif _is_f8(k.dtype):             # scale-less fp8: plain convert
         k = to_f32(k)
     if v_scales is not None:
-        vs = v_scales[page_table].reshape(b, pages_per_seq * page, hkv)
+        vs = gather_scales(v_scales, page_table)
         v = to_f32(v) * vs.astype(jnp.float32)[..., None]
     elif _is_f8(v.dtype):
         v = to_f32(v)
 
-    s = jnp.einsum("bhgd,bshd->bhgs", q.astype(jnp.float32),
+    s = jnp.einsum("bhgd,bhsd->bhgs", q.astype(jnp.float32),
                    k.astype(jnp.float32)) / math.sqrt(d)
     pos = jnp.arange(pages_per_seq * page)[None, :]
     valid = pos < seq_lens[:, None]
@@ -146,10 +144,10 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, seq_lens,
     p = jnp.exp(s - m)
     p = p / p.sum(axis=-1, keepdims=True)
     if extra_kv is not None:
-        o = jnp.einsum("bhgs,bshd->bhgd", p[..., :-1],
+        o = jnp.einsum("bhgs,bhsd->bhgd", p[..., :-1],
                        v.astype(jnp.float32))
         o = o + p[..., -1][..., None] * extra_kv[1][:, :, None, :].astype(
             jnp.float32)
     else:
-        o = jnp.einsum("bhgs,bshd->bhgd", p, v.astype(jnp.float32))
+        o = jnp.einsum("bhgs,bhsd->bhgd", p, v.astype(jnp.float32))
     return o.astype(q.dtype)

@@ -6,15 +6,11 @@ state — the dry-run sets XLA_FLAGS before any jax initialization.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5: meshes carry explicit axis types
-    from jax.sharding import AxisType
 
-    def _axis_kw(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # older jax: every axis is Auto implicitly
-    def _axis_kw(n: int) -> dict:
-        return {}
+def _axis_kw(n: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

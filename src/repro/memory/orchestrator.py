@@ -48,8 +48,11 @@ def donating_jit(fn: Callable, *, donate_argnums: tuple[int, ...] = (),
     Donating them lets XLA alias input and output so the cache is updated
     in place instead of copied once per dispatch.  ``config.donate_evicted
     = False`` turns the aliasing off (debug mode: old buffers stay live).
+    Under ``config.offload_kv`` nothing is donated: the cache arrives in
+    host memory, which cannot hold the step's device-resident result.
     """
-    if config is not None and not config.donate_evicted:
+    if config is not None and (not config.donate_evicted
+                               or config.offload_kv):
         donate_argnums = ()
     return jax.jit(fn, donate_argnums=donate_argnums, **jit_kwargs)
 
@@ -74,6 +77,21 @@ def _page_in_filtered(layer: Any, fetch_filter: Callable | None) -> Any:
         layer)
 
 
+def _vectors_in(stacked: Any, fetch_filter: Callable | None) -> Any:
+    """Page in, whole, the stacked leaves that hold one vector per layer
+    (norm scales, biases): on TPU one layer's slice of a host-resident
+    (L, N) array is a strided DMA whose stride the chip rejects (not a
+    multiple of the DMA granule), while a slice of an (L, a, b) matrix
+    stack is whole tiles.  The vectors are a few hundred KB; matrices
+    still stream one layer at a time."""
+    def pick(path, x):
+        if x.ndim > 2 or (fetch_filter is not None
+                          and not fetch_filter(jax.tree_util.keystr(path))):
+            return x
+        return tiers.page_in(x)
+    return jax.tree_util.tree_map_with_path(pick, stacked)
+
+
 def paged_scan(
     body: Callable[..., tuple[Any, Any]],
     carry: Any,
@@ -96,9 +114,12 @@ def paged_scan(
 
     ``xs`` is an optional extra per-layer input (e.g. the KV-cache slice for
     this layer).  With ``page_xs=True`` it is paged in alongside the weights
-    and the per-layer output ``out`` is written back to the remote tier
-    (FengHuang KV paging).  ``fetch_filter(leaf_path) -> bool`` excludes
-    weight leaves from the prefetch window (False = leaf stays at rest).
+    (FengHuang KV paging); the stacked outputs come back in device
+    memory.  Writing them back to the remote tier is the owner's eager
+    placement between dispatches (``MemoryOrchestrator.rest_kv_pool``):
+    a jitted program's outputs live in device memory.
+    ``fetch_filter(leaf_path) -> bool`` excludes weight leaves from the
+    prefetch window (False = leaf stays at rest).
     """
     if length is None:
         length = jax.tree.leaves(stacked_weights)[0].shape[0]
@@ -122,6 +143,8 @@ def paged_scan(
 
         return jax.lax.scan(step, carry, jnp.arange(length), unroll=unroll)
 
+    stacked_weights = _vectors_in(stacked_weights, fetch_filter)
+
     def fetch(i):
         return _page_in_filtered(_index_layer(stacked_weights, i),
                                  fetch_filter)
@@ -141,8 +164,6 @@ def paged_scan(
             if page_xs:
                 x = tiers.page_in(x)
             inner_carry, out = body(inner_carry, w_cur, x)
-            if page_xs:
-                out = tiers.page_out(out)
         return (inner_carry, w_next), out
 
     (carry, _), outs = jax.lax.scan(step, (carry, w0), jnp.arange(length),
@@ -169,12 +190,18 @@ def paged_scan_cache(
     iteration — the carried buffer is updated in place with a
     dynamic-update-slice (while-loop state aliases input/output), so
     per-layer traffic is just that layer's slice.  With
-    ``config.offload_kv`` the slice pages through the FengHuang remote
-    tier (page-in before attention, write-back after).
+    ``config.offload_kv`` the cache arrives from the FengHuang remote
+    tier: it is paged in once, because the loop-carried buffer must live
+    in device memory to take each layer's in-place update, and it is
+    returned in device memory.  The owner writes it back to the remote
+    tier between dispatches (``MemoryOrchestrator.rest_kv_pool``).
     """
     if length is None:
         length = jax.tree.leaves(stacked_weights)[0].shape[0]
     last = length - 1
+
+    if config.enabled:
+        stacked_weights = _vectors_in(stacked_weights, fetch_filter)
 
     def fetch(i):
         w = _index_layer(stacked_weights, i)
@@ -198,16 +225,14 @@ def paged_scan_cache(
         return carry, cache
 
     w0 = fetch(0)
+    if config.offload_kv:
+        cache = tiers.page_in(cache)
 
     def step(state, i):
         inner, cache_buf, w_cur = state
         w_next = fetch(jnp.minimum(i + 1, last))    # lookahead-1 prefetch
         cl = _index_layer(cache_buf, i)
-        if config.offload_kv:
-            cl = tiers.page_in(cl)
         inner, new_cl = body(inner, w_cur, cl)
-        if config.offload_kv:
-            new_cl = tiers.page_out(new_cl)
         return (inner, update(cache_buf, i, new_cl), w_next), None
 
     (carry, cache, _), _ = jax.lax.scan(step, (carry, cache, w0),
@@ -512,6 +537,24 @@ class MemoryOrchestrator:
             self.ledger.charge_transfer(tiers.LOCAL, policy.tier,
                                         tree_bytes(cache))
         return placed
+
+    def kv_in(self, cache: Any) -> Any:
+        """Page a remote-tier KV cache (``offload_kv``) into device memory
+        at the start of a traced step: XLA computes no update in host
+        memory.  The owner rests it back with :meth:`rest_kv_pool`."""
+        return tiers.page_in(cache) if self.config.offload_kv else cache
+
+    def rest_kv_pool(self, cache: Any) -> Any:
+        """Return a dispatch's KV cache to its policy's tier at rest: the
+        remote tier under ``offload_kv`` (a jitted step hands its cache
+        back in device memory), unchanged when the pool is local."""
+        policy = self.policies["kv_pool"]
+        if not isinstance(policy, OffloadBetweenSteps):
+            return cache
+        kind = tiers.resolved_kind(policy.tier)
+        return {k: (jax.device_put(v, v.sharding.with_memory_kind(kind))
+                    if k in policy.pool_keys else v)
+                for k, v in cache.items()}
 
     # ----- block pool -------------------------------------------------------
     def block_pool(self, num_pages: int, page_size: int,

@@ -203,9 +203,10 @@ class BlockPoolResidency:
         self._bytes_per_page = bytes_per_page
         self.k = self.v = None
         if kv_heads is not None and head_dim is not None:
-            self.k = jnp.zeros((num_pages, page_size, kv_heads, head_dim),
+            # the serving pool layout, (P, Hkv, page, hd)
+            self.k = jnp.zeros((num_pages, kv_heads, page_size, head_dim),
                                dtype)
-            self.v = jnp.zeros((num_pages, page_size, kv_heads, head_dim),
+            self.v = jnp.zeros((num_pages, kv_heads, page_size, head_dim),
                                dtype)
             if bytes_per_page is None:
                 self._bytes_per_page = self.manager.bytes_per_page(
@@ -308,8 +309,8 @@ class BlockPoolResidency:
         pos = pos0 + jnp.arange(t)
         pids = table[pos // self.page_size]
         slots = pos % self.page_size
-        self.k = self.k.at[pids, slots].set(k_blk.astype(self.k.dtype))
-        self.v = self.v.at[pids, slots].set(v_blk.astype(self.v.dtype))
+        self.k = self.k.at[pids, :, slots].set(k_blk.astype(self.k.dtype))
+        self.v = self.v.at[pids, :, slots].set(v_blk.astype(self.v.dtype))
         self.manager.lens[uid] = pos0 + t
         self.record()
 
@@ -438,5 +439,11 @@ class TopKExpertPrefetch:
             cap = max(getattr(self, "_local_cap", 0), nb)
             self._local_cap = cap
             self.ledger.record_capacity(tiers.LOCAL, self.tensor_class, cap)
-        return {k: tiers.page_in(jnp.take(banks[k], ids, axis=0))
-                for k in self.bank_keys}
+        # one row at a time: a gather cannot index a host-resident bank
+        # (XLA computes no gather in host memory), a dynamic slice can,
+        # and each slice crosses the tier edge on its own
+        def row(i):
+            return {k: tiers.page_in(jax.lax.dynamic_index_in_dim(
+                banks[k], i, 0, keepdims=False)) for k in self.bank_keys}
+
+        return jax.lax.map(row, ids)

@@ -38,7 +38,7 @@ class SwapHandle:
     """Remote-tier stash of one sequence's KV pages (host arrays).
 
     Quantized pools stash their per-slot dequant scales alongside the
-    values (``k_scale``/``v_scale``, (L, n, page, Hkv)) so a restore is
+    values (``k_scale``/``v_scale``, (L, n, Hkv, page)) so a restore is
     byte-for-byte the pages that were swapped out — the quantized
     preemption bit-identity contract.
 
@@ -58,7 +58,7 @@ class SwapHandle:
     bit-identically."""
 
     page_count: int
-    k: np.ndarray | None     # (L, n, page, Hkv, hd)
+    k: np.ndarray | None     # (L, n, Hkv, page, hd)
     v: np.ndarray | None
     nbytes: int
     k_scale: np.ndarray | None = None
@@ -160,6 +160,7 @@ class PageSwapper:
         per-pool ``jnp.take`` chain costs a device round trip per pool,
         which dominates small swaps — e.g. every prefill handoff)."""
         from repro.kernels.paged_attention.ref import gatherable_view
+        cache = tiers.page_in(cache)    # no gather in host memory
 
         def take(pool):
             # fp8 pools gather as a uint8 bit-view and bitcast back —
@@ -231,21 +232,22 @@ class PageSwapper:
     def _scatter_fn(self, cache: dict, pids: jax.Array, k: jax.Array,
                     v: jax.Array, k_scale: jax.Array | None = None,
                     v_scale: jax.Array | None = None) -> dict:
+        from repro.kernels.paged_attention.ops import (STACKED_POOL_SPEC,
+                                                       STACKED_SCALE_SPEC)
         from repro.runtime.sharding import maybe_constraint
-        from jax.sharding import PartitionSpec as P
-        k = maybe_constraint(k, P(None, None, None, "model", None))
-        v = maybe_constraint(v, P(None, None, None, "model", None))
+        cache = tiers.page_in(cache)    # no scatter in host memory
+        k = maybe_constraint(k, STACKED_POOL_SPEC)
+        v = maybe_constraint(v, STACKED_POOL_SPEC)
         out = dict(cache)
         out["k_pages"] = cache["k_pages"].at[:, pids].set(
             k.astype(cache["k_pages"].dtype))
         out["v_pages"] = cache["v_pages"].at[:, pids].set(
             v.astype(cache["v_pages"].dtype))
         if k_scale is not None:
-            sc = P(None, None, None, "model")
             out["k_scale"] = cache["k_scale"].at[:, pids].set(
-                maybe_constraint(k_scale, sc))
+                maybe_constraint(k_scale, STACKED_SCALE_SPEC))
             out["v_scale"] = cache["v_scale"].at[:, pids].set(
-                maybe_constraint(v_scale, sc))
+                maybe_constraint(v_scale, STACKED_SCALE_SPEC))
         return out
 
     def swap_in(self, cache: dict, page_ids: list[int],

@@ -6,29 +6,30 @@ kinds, as an ORDERED hierarchy (fastest first):
 * **local tier**  = ``memory_kind="device"`` (HBM),
 * **remote tier** = the best host-side kind the backend exposes —
   ``pinned_host`` (host DRAM behind the DMA engine; the TAB-attached
-  LPDDR6 pool in the paper's node) on GPU/TPU, ``unpinned_host`` on the
-  CPU backend (where local == remote, so paging degenerates to the
-  identity while keeping every transform's semantics intact),
+  LPDDR6 pool in the paper's node).  TPU and CPU backends both expose
+  ``device``, ``pinned_host`` and ``unpinned_host`` as distinct
+  memories, so a CPU run places and moves bytes exactly as the chip
+  does, and a missing host transfer fails on CPU as it would on TPU,
 * **cold tier**   = the capacity backstop (the High-Bandwidth-Flash
   direction in Ma & Patterson): the next distinct host kind after the
-  remote tier's, or — on backends exposing only one host kind — the
-  SAME kind as remote.  Tiers are logical levels of the hierarchy, not
-  memory kinds: on CPU all three share ``unpinned_host``, yet the
-  ledger, the swapper and the bandwidth model keep them distinct, so
-  the placement/accounting semantics are exactly what a real flash
-  tier would see.
+  remote tier's (``unpinned_host``), or — on a backend exposing only
+  one host kind — the SAME kind as remote.  Tiers are logical levels of
+  the hierarchy, not memory kinds: the ledger, the swapper and the
+  bandwidth model keep them distinct even when two share a kind.
+
+A backend that exposes no host memory kind cannot back the remote tier,
+and resolution raises instead of leaving the tier unbacked.
 
 Each :class:`Tier` carries a *modeled* ``bandwidth_gbps`` /
 ``latency_us`` for its link into the hierarchy; tier-edge transfer time
 (:meth:`TierRegistry.edge`) goes through the same
 :func:`repro.memory.accounting.modeled_transfer_s` formula the Table-4.3
-simulator's :class:`~repro.core.latency.LinkModel` uses, so measured
-(ledger-charged) and simulated transfer costs stay one code path.
+simulator's :class:`~repro.core.latency.LinkModel` uses, so ledger-
+charged and simulated transfer costs stay one code path.
 
-Resolution is cached **per backend** in a :class:`TierRegistry` — unlike
-the old module-level ``lru_cache`` in ``core.pager`` it is invalidated
-by :func:`reset` (used by tests and by anything that swaps the default
-backend mid-process, e.g. ``jax.config.update("jax_platform_name", …)``).
+Resolution is cached **per backend** in a :class:`TierRegistry`,
+invalidated by :func:`reset` (used by tests and by anything that swaps
+the default backend mid-process).
 """
 from __future__ import annotations
 
@@ -69,14 +70,10 @@ DEFAULT_TIER_LINKS: dict[str, tuple[float, float]] = {
     COLD: (64.0, 50.0),
 }
 
-try:  # public since jax 0.5
-    from jax.sharding import TransferToMemoryKind as _TransferToMemoryKind
-except ImportError:  # pragma: no cover - version specific
-    try:
-        from jax._src.sharding_impls import (
-            TransferToMemoryKind as _TransferToMemoryKind)
-    except ImportError:
-        _TransferToMemoryKind = None
+# Traced transfers name a memory space, not a kind: the two kinds the
+# paging transforms move between inside jit.
+_SPACES = {LOCAL_KIND: jax.memory.Space.Device,
+           REMOTE_KIND: jax.memory.Space.Host}
 
 
 def _link(name: str) -> tuple[float, float]:
@@ -89,9 +86,9 @@ class Tier:
     kind that backs it on the current backend (None = unavailable),
     plus the modeled bandwidth/latency of its link into the hierarchy.
 
-    Several tiers may share one memory kind (the CPU degenerate case:
-    remote and cold both resolve to ``unpinned_host``) — the logical
-    level, not the kind, is what the ledger and policies reason about.
+    Several tiers may share one memory kind (a backend with a single
+    host kind backs remote and cold with it) — the logical level, not
+    the kind, is what the ledger and policies reason about.
     """
 
     name: str
@@ -141,29 +138,25 @@ class TierRegistry:
         self._tiers: dict[str, dict[str, Tier]] = {}
 
     def _backend(self) -> str:
-        try:
-            return jax.default_backend()
-        except Exception:  # pragma: no cover - no backend at all
-            return "<none>"
+        return jax.default_backend()
 
     def _resolve(self, backend: str) -> dict[str, Tier]:
         """Resolve the ORDERED hierarchy (local, remote, cold) against
-        the backend's exposed memory kinds.  Backends with fewer
-        distinct kinds degenerate cleanly: the cold tier falls back to
-        the remote tier's host kind (and on CPU local aliases them too)
-        — tiers stay logically distinct even when physically aliased."""
-        try:
-            kinds = frozenset(
-                m.kind for m in jax.devices()[0].addressable_memories())
-        except Exception:  # pragma: no cover - platform specific
-            kinds = frozenset()
-        local = LOCAL_KIND if LOCAL_KIND in kinds else None
-        if local is None:
-            try:
-                local = jax.devices()[0].default_memory().kind
-            except Exception:  # pragma: no cover - platform specific
-                local = None
+        the backend's exposed memory kinds.  The cold tier falls back to
+        the remote tier's host kind on a backend with one host kind —
+        tiers stay logically distinct even when physically aliased.
+        Raises ``RuntimeError`` when the backend has no device memory
+        or no host memory kind: the remote tier would be unbacked, and
+        every transfer into it a silent no-op."""
+        kinds = frozenset(
+            m.kind for m in jax.devices()[0].addressable_memories())
         remote = next((k for k in _HOST_KINDS if k in kinds), None)
+        if LOCAL_KIND not in kinds or remote is None:
+            raise RuntimeError(
+                f"backend {backend!r} exposes memory kinds {sorted(kinds)}; "
+                f"the tier hierarchy needs {LOCAL_KIND!r} and one of "
+                f"{list(_HOST_KINDS)}")
+        local = LOCAL_KIND
         # cold: the next distinct host kind after remote's, else remote's
         cold = next((k for k in _HOST_KINDS
                      if k in kinds and k != remote), remote)
@@ -235,7 +228,7 @@ def reset() -> None:
 
 def resolved_kind(tier: str) -> str | None:
     """The memory kind backing ``tier`` on this backend (None for a
-    tier the backend cannot back — placement degenerates to a no-op)."""
+    tier name outside the hierarchy)."""
     t = _REGISTRY.tiers().get(tier)
     return t.kind if t is not None else None
 
@@ -257,7 +250,8 @@ def resolved_cold_kind() -> str | None:
 
 def supports_memory_spaces() -> bool:
     """True if the backend exposes a host memory kind the remote tier can
-    live in (distinct from HBM on GPU/TPU; aliased with it on CPU)."""
+    live in (resolution raises otherwise, so this is True wherever it
+    returns)."""
     return _REGISTRY.remote.available
 
 
@@ -451,10 +445,8 @@ def transfer_with_retry(fn: Callable[[], Any], *, what: str,
 def tier_sharding(mesh, pspec: P, tier: str) -> NamedSharding:
     """NamedSharding placing data in ``tier`` (any hierarchy level) with
     the memory kind the *current backend* actually exposes — resolved
-    through the registry, never hardcoded.  A ``None`` kind (tier not
-    backed on this platform) falls back to the backend default, so CPU —
-    where local == remote == cold == ``unpinned_host`` — degenerates
-    cleanly."""
+    through the registry, never hardcoded.  A tier name outside the
+    hierarchy gets the backend's default memory."""
     kind = _REGISTRY.tiers().get(tier, Tier(tier, None)).kind
     return NamedSharding(mesh, pspec, memory_kind=kind)
 
@@ -475,13 +467,13 @@ def to_remote(tree: Any, mesh, pspec_tree: Any) -> Any:
         tree, pspec_tree)
 
 
-def _put_kind(x: jax.Array, kind: str | None) -> jax.Array:
-    if kind is None:
-        return x
+def _put_kind(x: jax.Array, kind: str) -> jax.Array:
+    """Move ``x`` into memory ``kind``: a traced transfer inside jit
+    (device <-> pinned host only), an eager re-placement otherwise."""
     if isinstance(x, jax.core.Tracer):
-        if _TransferToMemoryKind is None:  # pragma: no cover - old jax
-            return x
-        return jax.device_put(x, _TransferToMemoryKind(kind))
+        if kind not in _SPACES:
+            raise ValueError(f"no traced transfer into memory kind {kind!r}")
+        return jax.device_put(x, _SPACES[kind])
     return jax.device_put(x, x.sharding.with_memory_kind(kind))
 
 

@@ -200,9 +200,12 @@ def attn_params(key, cfg: ModelConfig, *, cross: bool = False) -> dict:
         mask = (jnp.arange(hq) < cfg.num_heads).repeat(hd)
         wq = wq * mask[None, :].astype(wq.dtype)
     # init true KV heads, tile to the padded/replicated count so the
-    # architecture keeps its true number of distinct KV heads.
-    wk1 = dense_init(ks[1], (d, hkv_true, hd), cfg.dtype)
-    wv1 = dense_init(ks[2], (d, hkv_true, hd), cfg.dtype)
+    # architecture keeps its true number of distinct KV heads.  The
+    # fan-in is d_model (the head axis is not contracted).
+    wk1 = dense_init(ks[1], (d, hkv_true, hd), cfg.dtype,
+                     scale=1.0 / math.sqrt(d))
+    wv1 = dense_init(ks[2], (d, hkv_true, hd), cfg.dtype,
+                     scale=1.0 / math.sqrt(d))
     reps = hkv // hkv_true if hkv % hkv_true == 0 else 0
     if reps:
         wk = jnp.tile(wk1, (1, reps, 1)).reshape(d, hkv * hd)
@@ -210,10 +213,12 @@ def attn_params(key, cfg: ModelConfig, *, cross: bool = False) -> dict:
     else:  # pad with fresh heads (e.g. 36 -> 48)
         extra = hkv - hkv_true
         wk = jnp.concatenate(
-            [wk1, dense_init(ks[3], (d, extra, hd), cfg.dtype)],
+            [wk1, dense_init(ks[3], (d, extra, hd), cfg.dtype,
+                             scale=1.0 / math.sqrt(d))],
             axis=1).reshape(d, hkv * hd)
         wv = jnp.concatenate(
-            [wv1, dense_init(ks[4], (d, extra, hd), cfg.dtype)],
+            [wv1, dense_init(ks[4], (d, extra, hd), cfg.dtype,
+                             scale=1.0 / math.sqrt(d))],
             axis=1).reshape(d, hkv * hd)
     wo = dense_init(ks[5], (hq * hd, d), cfg.dtype)
     if hq > cfg.num_heads:
@@ -462,19 +467,19 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            extra_kv: tuple[jax.Array, jax.Array], *,
                            k_scales: jax.Array | None = None,
                            v_scales: jax.Array | None = None,
-                           use_kernel: bool | None = None,
-                           interpret: bool = False) -> jax.Array:
-    """Single-token attention against a (P, page, Hkv, hd) page pool.
+                           use_kernel: bool | None = None) -> jax.Array:
+    """Single-token attention against a (P, Hkv, page, hd) page pool.
 
     q: (B, 1, Hq, hd); page_table: (B, n_pages) int32 (null-page padded);
     cur_pos: (B,) — pooled positions < cur_pos are live, the current
     token arrives via ``extra_kv``.  ``k_scales``/``v_scales``
-    ((P, page, Hkv), quantized pools only) dequantize inline: the kernel
+    ((P, Hkv, page), quantized pools only) dequantize inline: the kernel
     rescales each page tile inside its online-softmax loop; the fallback
     rescales the gathered fp32 view — full-precision KV never
     materializes pool-wide either way.  Routed once per backend: the
     Pallas ``paged_attention`` kernel on TPU (scalar-prefetched page
-    tables), the gather + :func:`decode_attention` composition elsewhere.
+    tables, one call per head shard under a mesh), the gather +
+    :func:`decode_attention` composition elsewhere.
     """
     from repro.kernels.paged_attention import ops as paged_ops
 
@@ -482,12 +487,11 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     if use_kernel is None:
         use_kernel = paged_ops.use_pallas_kernel()
     if use_kernel:
-        hkv = k_pages.shape[2]
+        hkv = k_pages.shape[1]
         qg = q.reshape(b, hkv, hq // hkv, hd)
-        from repro.kernels.paged_attention.kernel import paged_attention
-        o = paged_attention(qg, k_pages, v_pages, page_table, cur_pos,
-                            extra_kv=extra_kv, k_scales=k_scales,
-                            v_scales=v_scales, interpret=interpret)
+        o = paged_ops.sharded_paged_attention(
+            qg, k_pages, v_pages, page_table, cur_pos, extra_kv=extra_kv,
+            k_scales=k_scales, v_scales=v_scales)
         return o.reshape(b, 1, hq, hd).astype(q.dtype)
     # spec-threaded gather: each device gathers only its "model" head
     # shard of the mapped pages, so tensor-parallel paged decode reads
@@ -513,8 +517,8 @@ def attn_decode_paged(p: dict, x: jax.Array, k_pages: jax.Array,
     """One-token self-attention over this layer's page pool (read-only —
     the (k, v) returned are written post-scan in one batched scatter).
 
-    x: (B, 1, d); [kv]_pages: (P, page, Hkv, hd); page_table: (B, n);
-    cur_pos: (B,); [kv]_scales: (P, page, Hkv) dequant scales when the
+    x: (B, 1, d); [kv]_pages: (P, Hkv, page, hd); page_table: (B, n);
+    cur_pos: (B,); [kv]_scales: (P, Hkv, page) dequant scales when the
     pool is quantized.  Returns (out (B,1,d), k0 (B,Hkv,hd), v0
     (B,Hkv,hd)) — k0/v0 full precision; the post-scan scatter quantizes.
     """

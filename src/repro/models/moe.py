@@ -136,11 +136,8 @@ def moe_ffn(p: dict, x: jax.Array, cfg: ModelConfig,
 # ---------------------------------------------------------------------------
 
 def _moe_ep_available(cfg: ModelConfig, s: int) -> bool:
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:   # pragma: no cover
-        return False
-    if am is None or getattr(am, "empty", True):
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return False
     sizes = dict(zip(am.axis_names, am.axis_sizes))
     tp = sizes.get("model", 1)

@@ -36,7 +36,7 @@ def _scatter_pages(cache: dict, pages: jax.Array, k_new: jax.Array,
     ``k_scale``/``v_scale`` arrays with the same scatter pattern, so a
     page's bytes are a pure function of the tokens it covers (the
     prefix-sharing contract)."""
-    page = cache["k_pages"].shape[2]
+    page = cache["k_pages"].shape[3]
     n = pages.shape[1]
     seq = k_new.shape[2]
     pad = n * page - seq
@@ -45,22 +45,23 @@ def _scatter_pages(cache: dict, pages: jax.Array, k_new: jax.Array,
                          f"prompt chunk has {seq}")
 
     def scatter(pool, val, spec):
-        # (L, B, S, ...) -> (L, B, n, page, ...), one scatter
+        # (L, B, S, Hkv, ...) -> (L, B, n, Hkv, page, ...), one scatter
         val = jnp.pad(val, ((0, 0), (0, 0), (0, pad))
                       + ((0, 0),) * (val.ndim - 3))
         l_, b_ = val.shape[:2]
         val = val.reshape((l_, b_, n, page) + val.shape[3:])
+        val = jnp.swapaxes(val, 3, 4)
         # under a mesh the update's head axis matches the pool's shard
         # layout, so the scatter stays device-local per head shard
         val = maybe_constraint(val, spec)
         return pool.at[:, pages].set(val.astype(pool.dtype))
 
-    kv_spec = P(None, None, None, None, "model", None)
+    kv_spec = P(None, None, None, "model", None, None)
     if cfg.kv_quantized:
         qdt, qmax = cfg.kv_pool_dtype(), cfg.kv_qmax()
         k_new, ks = L.kv_pool_quantize(k_new, qdt, qmax)
         v_new, vs = L.kv_pool_quantize(v_new, qdt, qmax)
-        sc_spec = P(None, None, None, None, "model")
+        sc_spec = P(None, None, None, "model", None)
         return {"k_pages": scatter(cache["k_pages"], k_new, kv_spec),
                 "v_pages": scatter(cache["v_pages"], v_new, kv_spec),
                 "k_scale": scatter(cache["k_scale"], ks, sc_spec),
@@ -267,12 +268,14 @@ class DenseLM:
 
     def init_paged_cache(self, num_pages: int,
                          page_size: int | None = None) -> dict:
-        """Stacked multi-layer page pools, (L, P, page, Hkv, hd).  Page 0
-        is the null page (never allocated; absorbs idle-slot writes).
+        """Stacked multi-layer page pools, (L, P, Hkv, page, hd): one page
+        holds every KV head, so the decode kernel DMAs a page as one
+        contiguous block.  Page 0 is the null page (never allocated;
+        absorbs idle-slot writes).
 
         With ``cfg.kv_dtype`` set the pools hold int8 / fp8 values and
-        per-(page, slot, head) bf16 absmax scales ride alongside in
-        ``k_scale``/``v_scale`` (L, P, page, Hkv) — dequant is fused into
+        per-(page, head, slot) bf16 absmax scales ride alongside in
+        ``k_scale``/``v_scale`` (L, P, Hkv, page) — dequant is fused into
         every pool read, so full-precision KV never materializes."""
         cfg = self.cfg
         if not self.supports_paged_kv():
@@ -280,7 +283,7 @@ class DenseLM:
                 "paged KV cache requires sliding_window == 0 and "
                 "kv_quant == False")
         page = page_size or cfg.page_size
-        shape = (cfg.num_layers, num_pages, page, cfg.padded_kv_heads,
+        shape = (cfg.num_layers, num_pages, cfg.padded_kv_heads, page,
                  cfg.head_dim)
         pool_dt = cfg.kv_pool_dtype()
         cache = {"k_pages": jnp.zeros(shape, pool_dt),
@@ -291,19 +294,20 @@ class DenseLM:
         return cache
 
     def paged_cache_specs(self) -> dict:
-        spec = P(None, None, None, "model", None)
-        specs = {"k_pages": spec, "v_pages": spec}
+        from repro.kernels.paged_attention.ops import (STACKED_POOL_SPEC,
+                                                       STACKED_SCALE_SPEC)
+        specs = {"k_pages": STACKED_POOL_SPEC, "v_pages": STACKED_POOL_SPEC}
         if self.cfg.kv_quantized:
             # scales shard on the head axis exactly like their pools
-            sc = P(None, None, None, "model")
-            specs["k_scale"] = sc
-            specs["v_scale"] = sc
+            specs["k_scale"] = STACKED_SCALE_SPEC
+            specs["v_scale"] = STACKED_SCALE_SPEC
         return specs
 
     def prefill(self, params: dict, tokens: jax.Array, cache: dict,
                 extra: dict | None = None):
         """Process the prompt, fill the cache, return last-position logits."""
         cfg = self.cfg
+        cache = self.mem.kv_in(cache)
         x = self._embed(params, tokens)
         if extra and "patches" in extra:
             x = jnp.concatenate([extra["patches"].astype(x.dtype), x], axis=1)
@@ -355,6 +359,7 @@ class DenseLM:
         per-slot splice.  Returns (last-position logits, cache).
         """
         cfg = self.cfg
+        cache = self.mem.kv_in(cache)
         x = self._embed(params, tokens)
         if extra and "patches" in extra:
             x = jnp.concatenate([extra["patches"].astype(x.dtype), x], axis=1)
@@ -401,9 +406,10 @@ class DenseLM:
                                                        gather_scales_sharded)
 
         cfg = self.cfg
+        cache = self.mem.kv_in(cache)
         x = self._embed(params, tokens)
         seq = x.shape[1]
-        page = cache["k_pages"].shape[2]
+        page = cache["k_pages"].shape[3]
         prefix_len = prefix_pages.shape[1] * page
         positions = prefix_len + jnp.arange(seq)
         quant = cfg.kv_quantized
@@ -465,6 +471,7 @@ class DenseLM:
         """tokens: (B, 1); cur_pos: (B,) absolute position being written;
         pages: (B, n_pages) block-pool page table (None = dense cache)."""
         cfg = self.cfg
+        cache = self.mem.kv_in(cache)
         x = self._embed(params, tokens)
         if pages is not None:
             x, cache = self._decode_pool(params, x, cache, cur_pos, pages)
@@ -560,7 +567,7 @@ class DenseLM:
         paged through the FengHuang remote tier)."""
         cfg = self.cfg
         b = x.shape[0]
-        page = cache["k_pages"].shape[2]
+        page = cache["k_pages"].shape[3]
         n_pages = pages.shape[1]
         bidx = jnp.arange(b)
         pi = cur_pos // page
@@ -583,14 +590,15 @@ class DenseLM:
                         k_scales=ksc, v_scales=vsc)
                     k0, k0s = L.kv_pool_quantize(k0, qdt, qmax)
                     v0, v0s = L.kv_pool_quantize(v0, qdt, qmax)
-                    ksc = ksc.at[pids, slots].set(k0s)
-                    vsc = vsc.at[pids, slots].set(v0s)
+                    ksc = ksc.at[pids, :, slots].set(k0s)
+                    vsc = vsc.at[pids, :, slots].set(v0s)
                 else:
                     kp, vp = cl
                     h, k0, v0 = self.block_decode_paged(lp, h, kp, vp, pages,
                                                         cur_pos)
-                kp = kp.at[pids, slots].set(k0.astype(kp.dtype))
-                vp = vp.at[pids, slots].set(v0.astype(vp.dtype))
+                # (P, Hkv, page, hd).at[pids, :, slots] takes (B, Hkv, hd)
+                kp = kp.at[pids, :, slots].set(k0.astype(kp.dtype))
+                vp = vp.at[pids, :, slots].set(v0.astype(vp.dtype))
                 return h, (kp, vp, ksc, vsc) if quant else (kp, vp)
 
             pools = ((cache["k_pages"], cache["v_pages"],
@@ -616,23 +624,28 @@ class DenseLM:
             body, x, params["layers"], xs=xs,
             unroll=cfg.decode_unroll)
         # one scatter per pool for all L layers and B slots — the fix for
-        # the old host-side PagePool.append's dispatch-per-token writes;
-        # the (L, B, Hkv, hd) updates keep the pool's head-shard layout
+        # the old host-side PagePool.append's dispatch-per-token writes.
+        # (L, P, Hkv, page, hd).at[:, pids, :, slots] takes its update as
+        # (B, L, Hkv, hd): the separated advanced indices lead
         if quant:
             k_new, ks = L.kv_pool_quantize(k_new, qdt, qmax)
             v_new, vs = L.kv_pool_quantize(v_new, qdt, qmax)
-        k_new = maybe_constraint(k_new, P(None, None, "model", None))
-        v_new = maybe_constraint(v_new, P(None, None, "model", None))
+        k_new = maybe_constraint(jnp.swapaxes(k_new, 0, 1),
+                                 P(None, None, "model", None))
+        v_new = maybe_constraint(jnp.swapaxes(v_new, 0, 1),
+                                 P(None, None, "model", None))
         cache = dict(cache)
-        cache["k_pages"] = cache["k_pages"].at[:, pids, slots].set(
+        cache["k_pages"] = cache["k_pages"].at[:, pids, :, slots].set(
             k_new.astype(cache["k_pages"].dtype))
-        cache["v_pages"] = cache["v_pages"].at[:, pids, slots].set(
+        cache["v_pages"] = cache["v_pages"].at[:, pids, :, slots].set(
             v_new.astype(cache["v_pages"].dtype))
         if quant:
-            ks = maybe_constraint(ks, P(None, None, "model"))
-            vs = maybe_constraint(vs, P(None, None, "model"))
-            cache["k_scale"] = cache["k_scale"].at[:, pids, slots].set(ks)
-            cache["v_scale"] = cache["v_scale"].at[:, pids, slots].set(vs)
+            ks = maybe_constraint(jnp.swapaxes(ks, 0, 1),
+                                  P(None, None, "model"))
+            vs = maybe_constraint(jnp.swapaxes(vs, 0, 1),
+                                  P(None, None, "model"))
+            cache["k_scale"] = cache["k_scale"].at[:, pids, :, slots].set(ks)
+            cache["v_scale"] = cache["v_scale"].at[:, pids, :, slots].set(vs)
         return x, cache
 
     def decode_loop(self, params: dict, cache: dict, state: DecodeState, *,
@@ -679,7 +692,8 @@ def sample_tokens_per_slot(logits: jax.Array, vocab: int, temperature: float,
 
 def decode_loop(model, params: dict, cache: dict, state: DecodeState, *,
                 num_steps: int, temperature: float = 0.0,
-                eos_id: int | None = None, detect_nonfinite: bool = False):
+                eos_id: int | None = None, detect_nonfinite: bool = False,
+                emit_logits: bool = False):
     """Fused on-device decode: ``num_steps`` tokens in ONE dispatch.
 
     A ``lax.scan`` over decode steps — any model exposing
@@ -716,8 +730,13 @@ def decode_loop(model, params: dict, cache: dict, state: DecodeState, *,
     request's NaN silently corrupt a whole batch's sampled stream.
     Slots that are inactive at a step are never flagged (their frozen
     garbage is harmless by construction).
+
+    ``emit_logits=True`` appends each step's logits, ``(B, num_steps,
+    V)`` in the model dtype, right before ``cache`` — what a caller
+    compares against a reference forward.
     """
     vocab = model.cfg.vocab
+    cache = model.mem.kv_in(cache)   # the scan carries a device cache
 
     def step(carry, _):
         cache, st = carry
@@ -751,12 +770,11 @@ def decode_loop(model, params: dict, cache: dict, state: DecodeState, *,
         if detect_nonfinite:
             bad = (~jnp.isfinite(logits).all(axis=(1, 2))) & emitted
             out = out + (bad,)
+        if emit_logits:
+            out = out + (logits[:, 0],)
         return (cache, new_state), out
 
     (cache, state), outs = jax.lax.scan(
         step, (cache, state), None, length=num_steps)
-    if detect_nonfinite:
-        toks, valid, bad = outs
-        return toks.T, valid.T, bad.T, cache, state
-    toks, valid = outs
-    return toks.T, valid.T, cache, state
+    outs = tuple(jnp.swapaxes(o, 0, 1) for o in outs)
+    return outs + (cache, state)

@@ -113,6 +113,9 @@ class Request:
     # terminal outcome, stamped exactly once by BatchedServer._finalize:
     # "completed" | "shed" | "rejected" | "expired" (None = in flight)
     outcome: str | None = None
+    # per emitted token, the (V,) logits it was sampled from — filled
+    # only by a server built with ``record_logits=True``
+    logits: list = dataclasses.field(default_factory=list)
     _pending_counted: bool = dataclasses.field(default=False, repr=False)
 
 
@@ -153,7 +156,8 @@ def make_serve_step(model, *, temperature: float = 0.0) -> Callable:
 
 def make_decode_loop(model, *, block_size: int, temperature: float = 0.0,
                      eos_id: int | None = None, donate: bool = True,
-                     detect_nonfinite: bool = False) -> Callable:
+                     detect_nonfinite: bool = False,
+                     emit_logits: bool = False) -> Callable:
     """Jit the fused decode loop with the donation contract: the cache
     (arg 1) and decode state (arg 2) are consumed by every dispatch.
 
@@ -175,8 +179,10 @@ def make_decode_loop(model, *, block_size: int, temperature: float = 0.0,
                 state, pages=state.pages.at[d_slots, d_cols].set(d_pids))
         return decode_loop(model, params, cache, state, num_steps=block_size,
                            temperature=temperature, eos_id=eos_id,
-                           detect_nonfinite=detect_nonfinite)
-    return memory.donating_jit(loop, donate_argnums=(1, 2) if donate else ())
+                           detect_nonfinite=detect_nonfinite,
+                           emit_logits=emit_logits)
+    return model.mem.donating_jit(loop,
+                                  donate_argnums=(1, 2) if donate else ())
 
 
 def _bucket(n: int, quantum: int = 8) -> int:
@@ -253,6 +259,11 @@ class BatchedServer:
     is kept for training only.)  Models without ``serving_param_specs``
     are rejected rather than served with silently diverging tokens.
 
+    ``record_logits=True`` keeps, per request, the logits every emitted
+    token was sampled from (``Request.logits``) — what a correctness
+    check compares against a reference forward.  It costs one host copy
+    of ``(batch, block, vocab)`` logits per block.
+
     ``deterministic=False`` opts OUT of that contract for raw speed:
     the output projections keep their Megatron row-parallel contraction
     shard (plain ``param_specs``) and the all-gather constraints stay
@@ -302,8 +313,10 @@ class BatchedServer:
                  max_pending: int | None = None,
                  overload_factor: float | None = None,
                  handoff_lease_blocks: int = 64,
-                 cold_park_after_blocks: int | None = None):
+                 cold_park_after_blocks: int | None = None,
+                 record_logits: bool = False):
         self.model = model
+        self.record_logits = bool(record_logits)
         self.batch = batch_size
         self.max_seq = max_seq
         self.block_size = block_size
@@ -333,6 +346,9 @@ class BatchedServer:
         if prefill_async and not self.paged:
             raise ValueError("prefill_async requires the paged KV cache "
                              "(the engines hand off pool pages)")
+        if prefill_async and record_logits:
+            raise ValueError("record_logits does not cover handoff "
+                             "adoption (prefill_async)")
         self._prefill_async = bool(prefill_async)
         self._prefill_chunk_tokens = prefill_chunk_tokens
         # the model's orchestrator (shared ledger: weight windows, expert
@@ -450,7 +466,8 @@ class BatchedServer:
         self.prefix_cache = bool(prefix_cache)
         self._decode_loop = make_decode_loop(
             model, block_size=self.block_size, temperature=self.temperature,
-            eos_id=self.eos_id, detect_nonfinite=True)
+            eos_id=self.eos_id, detect_nonfinite=True,
+            emit_logits=self.record_logits)
         self._admit_step = self.mem.donating_jit(self._make_admit_step(),
                                                  donate_argnums=(2, 3))
         self._admit_step_prefix = None
@@ -859,7 +876,7 @@ class BatchedServer:
             plen = ptoks.shape[1]
             state = self._spliced_state(state, nxt, plen, slot, max_new,
                                         req_key)
-            return nxt, cache, state
+            return nxt, logits, cache, state
         return admit_step
 
     def _make_admit_step_paged(self) -> Callable:
@@ -878,7 +895,7 @@ class BatchedServer:
             plen = ptoks.shape[1]
             state = self._spliced_state(state, nxt, plen, slot, max_new,
                                         req_key)
-            return nxt, cache, state
+            return nxt, logits, cache, state
         return admit_step
 
     def _make_admit_step_prefix(self) -> Callable:
@@ -897,13 +914,13 @@ class BatchedServer:
             admission stay PRNG-identical."""
             logits, cache = model.prefill_paged_prefix(
                 params, ptoks, cache, prefix_pages, new_pages)
-            page = cache["k_pages"].shape[2]
+            page = cache["k_pages"].shape[3]
             plen = prefix_pages.shape[1] * page + ptoks.shape[1]
             k = jax.random.fold_in(req_key, plen)
             nxt = sample_tokens(logits, vocab, temperature, k)   # (1, 1)
             state = self._spliced_state(state, nxt, plen, slot, max_new,
                                         req_key)
-            return nxt, cache, state
+            return nxt, logits, cache, state
         return admit_step
 
     def _spliced_state(self, state, nxt, plen, slot, max_new, req_key):
@@ -1053,7 +1070,8 @@ class BatchedServer:
                     suffix = toks[:, len(shared) * self.page_size:]
                     self._note_prefill_dispatch(suffix.shape[1])
                     with self._mesh_ctx():
-                        nxt, self.cache, self.state = self._admit_step_prefix(
+                        (nxt, logits, self.cache,
+                         self.state) = self._admit_step_prefix(
                             self.params, jnp.asarray(suffix), self.cache,
                             self.state, jnp.asarray(slot, jnp.int32),
                             jnp.asarray(req.max_new_tokens, jnp.int32),
@@ -1066,7 +1084,7 @@ class BatchedServer:
                     ptable = jnp.asarray([new_ids], jnp.int32)
                     self._note_prefill_dispatch(plen)
                     with self._mesh_ctx():
-                        nxt, self.cache, self.state = self._admit_step(
+                        nxt, logits, self.cache, self.state = self._admit_step(
                             self.params, jnp.asarray(toks), self.cache,
                             self.state, jnp.asarray(slot, jnp.int32),
                             jnp.asarray(req.max_new_tokens, jnp.int32),
@@ -1079,7 +1097,7 @@ class BatchedServer:
             else:
                 self._note_prefill_dispatch(plen)
                 with self._mesh_ctx():
-                    nxt, self.cache, self.state = self._admit_step(
+                    nxt, logits, self.cache, self.state = self._admit_step(
                         self.params, jnp.asarray(toks), self.cache,
                         self.state, jnp.asarray(slot, jnp.int32),
                         jnp.asarray(req.max_new_tokens, jnp.int32), req_key)
@@ -1087,12 +1105,15 @@ class BatchedServer:
             if saved_pages is not None and self.state.pages is None:
                 self.state = dataclasses.replace(self.state,
                                                  pages=saved_pages)
+        self.cache = self.mem.rest_kv_pool(self.cache)
         self._slot_pos[slot] = plen
         self._sched_counter += 1
         self._last_sched[slot] = self._sched_counter
         req.admitted_at_block = self.stats["blocks"]
         first = int(jax.device_get(nxt)[0, 0])
         req.output.append(first)
+        if self.record_logits:
+            req.logits.append(np.asarray(jax.device_get(logits))[0, -1])
         self._record_first_token(req)
         self.stats["tokens"] += 1
         self.stats["admitted"] += 1
@@ -1678,18 +1699,18 @@ class BatchedServer:
             self.kv.record()
             self._note_peak()
             with self._mesh_ctx():
-                toks, valid, poison, self.cache, self.state = \
-                    self._decode_loop(self.params, self.cache, self.state,
-                                      delta)
+                *outs, self.cache, self.state = self._decode_loop(
+                    self.params, self.cache, self.state, delta)
         else:
             with self._mesh_ctx():
-                toks, valid, poison, self.cache, self.state = \
-                    self._decode_loop(self.params, self.cache, self.state)
+                *outs, self.cache, self.state = self._decode_loop(
+                    self.params, self.cache, self.state)
+        self.cache = self.mem.rest_kv_pool(self.cache)
         self._fold_stall()
         self.stats["dispatches"] += 1
         self.stats["blocks"] += 1
         self.stats["steps"] += self.block_size
-        return toks, valid, poison, advances
+        return outs, advances
 
     def _harvest(self, block, finished: list[Request]) -> None:
         """Sync ONE in-flight block's token harvest (the only host sync
@@ -1702,8 +1723,8 @@ class BatchedServer:
         its own tail page — and any reallocation of that page is either
         fully overwritten (admission prefill writes whole pages) or
         masked until the new owner actually writes each position."""
-        toks, valid, poison, advances = block
-        toks_h, valid_h, poison_h = jax.device_get((toks, valid, poison))
+        outs, advances = block
+        toks_h, valid_h, poison_h, *logits_h = jax.device_get(outs)
         self.stats["host_syncs"] += 1
         for i, (req, adv) in advances.items():
             if self.slots[i] is req:
@@ -1724,6 +1745,8 @@ class BatchedServer:
                     bad = True            # this and later tokens: garbage
                     break
                 req.output.append(int(toks_h[i, t]))
+                if logits_h:
+                    req.logits.append(logits_h[0][i, t])
                 emitted += 1
                 self.stats["tokens"] += 1
             self._slot_pos[i] += emitted
@@ -1977,7 +2000,7 @@ class BatchedServer:
         kp = self.cache["k_pages"]
         sc = self.cache.get("k_scale")
         per_page = self.manager.bytes_per_page(
-            kp.shape[3], kp.shape[4], kp.dtype.itemsize,
+            kp.shape[2], kp.shape[4], kp.dtype.itemsize,
             num_layers=kp.shape[0],
             scale_itemsize=(sc.dtype.itemsize if sc is not None else 0))
         return self.manager.pages_in_use * per_page

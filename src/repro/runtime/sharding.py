@@ -34,11 +34,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.memory import tiers as memtiers
 from repro.models.base import BATCH_AXES
 
-try:  # jax <= 0.4 ambient-mesh plumbing (Mesh context manager)
-    from jax.interpreters import pxla as _pxla
-except ImportError:  # pragma: no cover - future jax without the shim
-    _pxla = None
-
 PAGEABLE_GROUPS = ("layers", "groups", "dec_layers", "enc_layers")
 
 
@@ -78,8 +73,8 @@ def named_shardings(spec_tree: Any, mesh: Mesh, *,
     in the FengHuang remote tier — the weights will be paged into local
     memory by the orchestrator's layer scans.  Both tiers' memory kinds
     come from the :class:`~repro.memory.tiers.TierRegistry` for the
-    current backend (``pinned_host`` remote on GPU/TPU, ``unpinned_host``
-    on CPU — the old hardcoded kind broke CPU placement entirely).
+    current backend (``device`` local, ``pinned_host`` remote on TPU and
+    CPU alike).
     """
 
     def convert(path, s):
@@ -117,31 +112,15 @@ def constraint(x, mesh: Mesh, spec: P):
 # ---------------------------------------------------------------------------
 
 def mesh_axis_sizes(mesh) -> dict[str, int]:
-    """Axis name -> size for a concrete ``Mesh`` or an ``AbstractMesh``
-    — the ONE size-lookup used everywhere (no per-call duck typing)."""
-    if hasattr(mesh, "axis_sizes"):         # AbstractMesh (jax >= 0.5)
-        return dict(zip(mesh.axis_names, mesh.axis_sizes))
-    return dict(mesh.shape)                 # Mesh: OrderedDict name->size
+    """Axis name -> size for a ``Mesh`` or an ``AbstractMesh``."""
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def ambient_mesh():
-    """The mesh enclosing the current trace, or None.
-
-    jax >= 0.5 exposes it as :func:`jax.sharding.get_abstract_mesh`;
-    jax <= 0.4 tracks the ``with mesh:`` context in
-    ``pxla.thread_resources``.  Neither probe swallows real errors — a
-    broken mesh propagates instead of silently no-op'ing constraints.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        am = get()
-        if am is not None and not getattr(am, "empty", False):
-            return am
-    env = getattr(_pxla, "thread_resources", None)
-    mesh = getattr(getattr(env, "env", None), "physical_mesh", None)
-    if mesh is not None and not mesh.empty:
-        return mesh
-    return None
+    """The (abstract) mesh enclosing the current trace or call — set by
+    :func:`activate_mesh` — or None."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 def activate_mesh(mesh: Mesh | None):
@@ -150,10 +129,7 @@ def activate_mesh(mesh: Mesh | None):
     ``None`` is a no-op context (single-device serving)."""
     if mesh is None:
         return contextlib.nullcontext()
-    use = getattr(jax.sharding, "use_mesh", None)   # jax >= 0.5
-    if use is not None:
-        return use(mesh)
-    return mesh          # jax <= 0.4: Mesh is itself a context manager
+    return jax.set_mesh(mesh)
 
 
 def maybe_constraint(x, spec: P):
@@ -186,7 +162,15 @@ def maybe_constraint(x, spec: P):
             out.append(None)
     if all(e is None for e in out):
         return x
-    return jax.lax.with_sharding_constraint(x, P(*out))
+    return _constrain(x, P(*out))
+
+
+def _constrain(x, spec: P):
+    """Sharding constraint against the ambient mesh; an eager array is
+    placed on the concrete mesh :func:`activate_mesh` set."""
+    if not isinstance(x, jax.core.Tracer):
+        spec = NamedSharding(jax.sharding.get_mesh(), spec)
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 _TP_STATE = threading.local()
@@ -222,7 +206,7 @@ def replicate_constraint(x):
         return x
     if ambient_mesh() is None:
         return x
-    return jax.lax.with_sharding_constraint(x, P())
+    return _constrain(x, P())
 
 
 # ---------------------------------------------------------------------------

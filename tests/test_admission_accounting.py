@@ -12,13 +12,11 @@ import queue as queue_mod
 import jax
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # tier-1 runs without hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import build_model, get_config
 from repro.kernels.paged_attention.ops import BlockManager
+from repro.memory import BlockPoolResidency
 from repro.runtime.serve import BatchedServer, Request, _Preempted
 
 MAX_SEQ = 64
@@ -143,7 +141,10 @@ class _SchedHarness(BatchedServer):
         self.max_seq = MAX_SEQ
         self.batch = batch
         self.page_size = PAGE
-        self.manager = BlockManager(num_pages, PAGE)
+        # host-only pool residency (no ledger): the scheduler's record()
+        # calls run for real and record nothing
+        self.kv = BlockPoolResidency(num_pages, PAGE)
+        self.manager = self.kv.manager
         self.slots: list[Request | None] = [None] * batch
         self._init_sched_state(batch)
         self.events: list[tuple[str, int]] = []

@@ -2,10 +2,7 @@
 import math
 
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # tier-1 runs without hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import analysis, hw, latency
 

@@ -51,8 +51,6 @@ def test_xla_cost_analysis_undercounts_loops():
         return jax.lax.scan(lambda c, wi: (c @ wi, None), a, w)[0]
 
     ca = jax.jit(scanned).lower(x, ws).compile().cost_analysis()
-    if isinstance(ca, list):     # jax < 0.5 returns one dict per device
-        ca = ca[0]
     walker = _cost(scanned, x, ws)["flops"]
     assert walker > 5 * ca["flops"]
 

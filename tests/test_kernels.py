@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # tier-1 runs without hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.streamed_matmul import ops as sm
 from repro.kernels.flash_attention import ops as fa
@@ -123,8 +120,8 @@ def test_flash_matches_model_layer_path():
 def test_paged_attention_sweep(b, hkv, g, npages, page, dtype, extra):
     d = 32
     pool = npages * b + 1
-    kp = jnp.asarray(RNG.randn(pool, page, hkv, d), dtype) * 0.3
-    vp = jnp.asarray(RNG.randn(pool, page, hkv, d), dtype)
+    kp = jnp.asarray(RNG.randn(pool, hkv, page, d), dtype) * 0.3
+    vp = jnp.asarray(RNG.randn(pool, hkv, page, d), dtype)
     q = jnp.asarray(RNG.randn(b, hkv, g, d), dtype) * 0.3
     table = jnp.asarray(
         1 + np.arange(b * npages).reshape(b, npages), jnp.int32)

@@ -29,9 +29,28 @@ def test_registry_resolves_current_backend():
     reg = tiers.registry()
     assert reg.local.available
     assert reg.remote.available == memory.supports_memory_spaces()
-    # CPU backend: remote degenerates to unpinned_host, local aliases it
-    if jax.default_backend() == "cpu":
-        assert reg.remote.kind == "unpinned_host"
+    # CPU and TPU backends expose device / pinned_host / unpinned_host as
+    # distinct memories: local is HBM, remote the pinned host pool
+    if jax.default_backend() in ("cpu", "tpu"):
+        assert reg.local.kind == "device"
+        assert reg.remote.kind == "pinned_host"
+        assert reg.cold.kind == "unpinned_host"
+
+
+def test_registry_raises_without_a_host_memory_kind(monkeypatch):
+    """A backend with no host memory kind cannot back the remote tier:
+    resolution raises instead of leaving the tier unbacked (every
+    page_out would otherwise be a silent no-op)."""
+    class _Mem:
+        kind = "device"
+
+    class _Dev:
+        def addressable_memories(self):
+            return [_Mem()]
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    with pytest.raises(RuntimeError, match="pinned_host"):
+        tiers.TierRegistry()._resolve("fake")
 
 
 def test_registry_rescopes_after_backend_change(monkeypatch):

@@ -36,8 +36,8 @@ def tiny_model():
 
 def _pool(b, npages, page, hkv, d, dtype=jnp.float32):
     pool = 1 + b * npages
-    kp = jnp.asarray(RNG.randn(pool, page, hkv, d), dtype) * 0.3
-    vp = jnp.asarray(RNG.randn(pool, page, hkv, d), dtype)
+    kp = jnp.asarray(RNG.randn(pool, hkv, page, d), dtype) * 0.3
+    vp = jnp.asarray(RNG.randn(pool, hkv, page, d), dtype)
     table = jnp.asarray(1 + np.arange(b * npages).reshape(b, npages),
                         jnp.int32)
     return kp, vp, table
@@ -110,9 +110,12 @@ def test_paged_kernel_seq_len_zero_with_self_column():
         atol=2e-5, rtol=2e-5)
 
 
-def test_paged_decode_attention_backend_routing():
+def test_paged_decode_attention_backend_routing(monkeypatch):
     """Kernel (interpret) and gather fallback agree through the layer-level
     entry point, q in (B, 1, Hq, hd) layout."""
+    import functools
+    monkeypatch.setattr(pa, "paged_attention", functools.partial(
+        pa.paged_attention, interpret=True))
     b, hkv, g, d, page = 2, 2, 2, 16, 8
     kp, vp, table = _pool(b, 2, page, hkv, d)
     q = jnp.asarray(RNG.randn(b, 1, hkv * g, d), jnp.float32) * 0.3
@@ -122,7 +125,7 @@ def test_paged_decode_attention_backend_routing():
     a = paged_decode_attention(q, kp, vp, table, cur, (k0, v0),
                                use_kernel=False)
     k = paged_decode_attention(q, kp, vp, table, cur, (k0, v0),
-                               use_kernel=True, interpret=True)
+                               use_kernel=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(k),
                                atol=2e-5, rtol=2e-5)
 
@@ -367,3 +370,16 @@ def test_server_paged_offload_kv(tiny_model):
         return r.output
 
     assert run(omodel) == run(model)
+
+def test_server_offloaded_pool_rests_in_remote_tier(tiny_model):
+    """Between dispatches an offload_kv server keeps its page pools in
+    the remote tier (pinned host memory), not where the jitted step
+    returned them."""
+    model, params = tiny_model
+    omodel = build_model(model.cfg.with_pager(enabled=True,
+                                              offload_kv=True))
+    server = BatchedServer(omodel, params, batch_size=2, max_seq=64)
+    server.submit(np.asarray([3, 1, 4], np.int32), max_new_tokens=4)
+    server.run_once()
+    for k in ("k_pages", "v_pages"):
+        assert server.cache[k].sharding.memory_kind == "pinned_host"

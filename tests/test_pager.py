@@ -103,3 +103,45 @@ def test_page_roundtrip():
     h = pager.page_out(x)
     d = pager.page_in(h)
     np.testing.assert_array_equal(np.asarray(d), np.asarray(x))
+
+
+def test_paged_scan_streams_pinned_host_weights(ws):
+    """Weights at rest in pinned host memory stream through the paged
+    scan and give the unpaged scan's result; eager page_out lands in
+    host memory and page_in brings it back to the device."""
+    c0 = jnp.ones((2, 16))
+    ws_host = pager.host_put(ws)
+    assert ws_host.sharding.memory_kind == "pinned_host"
+    ref = jax.jit(lambda c, w: jax.lax.scan(body, c, w))(c0, ws)
+    got = jax.jit(lambda c, w: pager.paged_scan(
+        body, c, w, config=pager.PagerConfig(enabled=True)))(c0, ws_host)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(ref[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
+    out = pager.page_out(got[0])
+    assert out.sharding.memory_kind == "pinned_host"
+    assert pager.page_in(out).sharding.memory_kind == "device"
+
+
+def test_offloaded_cache_rests_in_host_memory(ws):
+    """offload_kv: a host-resident cache is paged in for the step, comes
+    back from the jitted step in device memory, and the orchestrator's
+    rest placement puts it back in the remote tier — checked, not
+    assumed."""
+    L = ws.shape[0]
+    cfg = pager.PagerConfig(enabled=True, offload_kv=True)
+    mem = pager.MemoryOrchestrator.plan(pager_config=cfg)
+    cache = mem.place_kv_pool({"k_pages": jnp.zeros((L, 2, 16))})
+    assert cache["k_pages"].sharding.memory_kind == "pinned_host"
+
+    def cbody(c, w, cl):
+        return jnp.tanh(c @ w + cl.sum() * 0.01), cl + 1.0
+
+    step = mem.donating_jit(lambda c, w, ca: mem.layer_scan_cache(
+        lambda h, lw, cl: (lambda o: (o[0], {"k_pages": o[1]}))(
+            cbody(h, lw, cl["k_pages"])), c, w, ca), donate_argnums=(2,))
+    _, new = step(jnp.ones((2, 16)), pager.host_put(ws), cache)
+    assert new["k_pages"].sharding.memory_kind == "device"
+    rested = mem.rest_kv_pool(new)
+    assert rested["k_pages"].sharding.memory_kind == "pinned_host"
+    np.testing.assert_array_equal(np.asarray(rested["k_pages"]),
+                                  np.ones((L, 2, 16)))

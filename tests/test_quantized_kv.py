@@ -180,7 +180,7 @@ def test_per_page_scales_do_not_bleed(quant_model):
     pages = jnp.asarray([[1, 2, 3]], jnp.int32)
     tokens = jnp.asarray(np.arange(1, seq + 1)[None], jnp.int32)
     _, cache = jax.jit(model.prefill_paged)(params, tokens, cache, pages)
-    ks = np.asarray(cache["k_scale"], np.float32)    # (L, P, page, Hkv)
+    ks = np.asarray(cache["k_scale"], np.float32)    # (L, P, Hkv, page)
     live = ks[:, 1:4]
     assert np.all(live > 0)
     # scales are PER page slot: pages see different activations, so a
@@ -204,8 +204,8 @@ def test_kernel_matches_ref_with_scales(name, dt, qmax):
     b, hkv, g, hd, n_pages, page, pool = 2, 2, 2, 64, 3, 8, 8
     ks = jax.random.split(key, 5)
     q = jax.random.normal(ks[0], (b, hkv, g, hd), jnp.float32)
-    kf = jax.random.normal(ks[1], (pool, page, hkv, hd), jnp.float32)
-    vf = jax.random.normal(ks[2], (pool, page, hkv, hd), jnp.float32)
+    kf = jax.random.normal(ks[1], (pool, hkv, page, hd), jnp.float32)
+    vf = jax.random.normal(ks[2], (pool, hkv, page, hd), jnp.float32)
     k_pages, k_scales = L.kv_pool_quantize(kf, dt, qmax)
     v_pages, v_scales = L.kv_pool_quantize(vf, dt, qmax)
     table = jax.random.randint(ks[3], (b, n_pages), 1, pool, jnp.int32)

@@ -234,3 +234,27 @@ def test_server_uses_configured_temperature(tiny_model):
     # with the old hardcoded temperature=0.0 the first token is greedy,
     # hence identical for every seed; at temperature 2.0 it must vary
     assert len({first_token(s) for s in range(4)}) > 1
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_server_records_logits_of_every_emitted_token(tiny_model, paged):
+    """record_logits keeps the logits each token was sampled from: at
+    temperature 0 every token is the argmax of its own logits, across
+    admission (prefill) and block decode, with no effect on tokens."""
+    model, params = tiny_model
+    prompts = [np.asarray([5, 6, 7], np.int32), np.asarray([9, 10], np.int32),
+               np.asarray([1, 2, 3, 4], np.int32)]
+
+    def serve(record):
+        server = BatchedServer(model, params, batch_size=2, max_seq=64,
+                               paged=paged, record_logits=record)
+        reqs = [server.submit(p, max_new_tokens=7) for p in prompts]
+        server.run_once()
+        return reqs
+
+    plain, recorded = serve(False), serve(True)
+    for a, r in zip(plain, recorded):
+        assert r.output == a.output and a.logits == []
+        assert len(r.logits) == len(r.output) == 7
+        vocab = model.cfg.vocab
+        assert [int(np.argmax(lg[:vocab])) for lg in r.logits] == r.output

@@ -24,11 +24,9 @@ from repro.runtime.sharding import (SEQ_SHARDED_ACTS, ambient_mesh,
 # ---------------------------------------------------------------------------
 
 def test_named_shardings_resolves_tiers_on_cpu_backend():
-    """Regression: the remote tier used to be hardcoded ``pinned_host``
-    (and local ``device``) — on the CPU backend neither kind exists
-    (only ``unpinned_host``) so every construction raised.  Routed
-    through the registry, both tiers resolve to backend-real kinds and
-    the shardings actually place arrays."""
+    """Memory kinds come from the tier registry, never hardcoded: both
+    tiers resolve to backend-real kinds and the shardings actually
+    place arrays."""
     mesh = make_smoke_mesh()
     spec_tree = {
         "embed": {"tok": P("model", None)},
@@ -38,10 +36,10 @@ def test_named_shardings_resolves_tiers_on_cpu_backend():
     assert sh["layers"]["attn"]["wq"].memory_kind == \
         tiers.resolved_remote_kind()
     assert sh["embed"]["tok"].memory_kind == tiers.resolved_local_kind()
-    if jax.default_backend() == "cpu":
-        # the exact CPU shape of the old bug: no pinned_host, no device
-        assert sh["layers"]["attn"]["wq"].memory_kind == "unpinned_host"
-        assert sh["embed"]["tok"].memory_kind == "unpinned_host"
+    if jax.default_backend() in ("cpu", "tpu"):
+        # both backends expose the chip's kinds: HBM and pinned host
+        assert sh["layers"]["attn"]["wq"].memory_kind == "pinned_host"
+        assert sh["embed"]["tok"].memory_kind == "device"
     # placement must work, not just construct
     placed = jax.device_put(jnp.zeros((4, 4)), sh["embed"]["tok"])
     assert placed.sharding.memory_kind == tiers.resolved_local_kind()
@@ -110,8 +108,8 @@ sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.mesh import make_host_mesh
-from repro.runtime.sharding import (SEQ_SHARDED_ACTS, ambient_mesh,
-                                    collective_bytes_by_axis,
+from repro.runtime.sharding import (SEQ_SHARDED_ACTS, activate_mesh,
+                                    ambient_mesh, collective_bytes_by_axis,
                                     maybe_constraint, mesh_axis_sizes)
 
 mesh = make_host_mesh(data=2, model=4)
@@ -122,7 +120,7 @@ x = jnp.ones((4, 8, 6))
 assert ambient_mesh() is None
 assert maybe_constraint(x, SEQ_SHARDED_ACTS) is x
 
-with mesh:
+with activate_mesh(mesh):
     am = ambient_mesh()
     assert am is not None, "ambient mesh not detected inside the context"
     assert mesh_axis_sizes(am) == {"data": 2, "model": 4}
@@ -141,7 +139,7 @@ with mesh:
 # gather_tp_mode (the serving dispatch context) — a bare mesh context
 # (e.g. the dry-run cost model) must leave it a strict no-op
 from repro.runtime.sharding import gather_tp_mode, replicate_constraint
-with mesh:
+with activate_mesh(mesh):
     assert replicate_constraint(x) is x, "fired outside gather_tp_mode"
     with gather_tp_mode():
         assert replicate_constraint(x) is not x, "did not fire when armed"

@@ -1,9 +1,6 @@
 """FengHuang simulator: paper-claim validation + scheduling invariants."""
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # tier-1 runs without hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import graphs as G
 from repro.core import hw, simulator as S

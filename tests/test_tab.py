@@ -18,16 +18,9 @@ from jax.sharding import PartitionSpec as P
 from repro.core import tab
 
 n = 8
-try:                                    # jax >= 0.5 axis types
-    from jax.sharding import AxisType
-    mesh = jax.make_mesh((n,), ("model",), axis_types=(AxisType.Auto,))
-except ImportError:
-    mesh = jax.make_mesh((n,), ("model",))
-try:                                    # jax >= 0.5 public shard_map
-    shard_map, _sm_kw = jax.shard_map, {"check_vma": False}
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
-    _sm_kw = {"check_rep": False}
+from jax.sharding import AxisType
+mesh = jax.make_mesh((n,), ("model",), axis_types=(AxisType.Auto,))
+shard_map, _sm_kw = jax.shard_map, {"check_vma": False}
 
 rng = np.random.RandomState(0)
 x = jnp.asarray(rng.randn(n * 4, 16), jnp.float32)
