@@ -59,14 +59,17 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import os
 import queue
 import threading
+import time
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import memory
 from repro.memory import MemoryOrchestrator
@@ -116,6 +119,11 @@ class Request:
     # per emitted token, the (V,) logits it was sampled from — filled
     # only by a server built with ``record_logits=True``
     logits: list = dataclasses.field(default_factory=list)
+    # host clock (time.perf_counter): when submit() queued the request,
+    # and when it left the backlog for its first prefill (monolithic
+    # admission or the prefill engine's start; a resume keeps it)
+    t_submit: float | None = None
+    t_admit: float | None = None
     _pending_counted: bool = dataclasses.field(default=False, repr=False)
 
 
@@ -425,6 +433,10 @@ class BatchedServer:
         self._stall_tokens = 0
         self._ttft_samples: list[int] = []
         self._e2e_samples: list[int] = []
+        # what held the backlog head at the last admission check
+        # ("pages", "slots" or None) and since when (host clock)
+        self._blocked: str | None = None
+        self._blocked_since = 0.0
         self.stats = {"steps": 0, "tokens": 0, "batches": 0, "blocks": 0,
                       "dispatches": 0, "admitted": 0, "host_syncs": 0,
                       "kv_pages_in_use": 0, "kv_pages_hwm": 0,
@@ -445,7 +457,13 @@ class BatchedServer:
                       "completed": 0, "rejected": 0, "expired": 0,
                       "poison_sheds": 0, "engine_crashes": 0,
                       "lease_reclaims": 0, "crash_requeues": 0,
-                      "e2e_p50_blocks": 0.0, "e2e_p99_blocks": 0.0}
+                      "e2e_p50_blocks": 0.0, "e2e_p99_blocks": 0.0,
+                      # the paged kernel's grid steps per layer, and
+                      # those on a page holding an attended position
+                      "kv_pages_visited": 0, "kv_pages_live": 0,
+                      # host seconds the backlog head waited, by cause
+                      "queue_blocked_pages_s": 0.0,
+                      "queue_blocked_slots_s": 0.0}
 
     def _init_live_state(self, model, params, spec_fn, batch_size, max_seq,
                          seed, page_size, num_pages, pipeline, prefix_cache,
@@ -609,7 +627,8 @@ class BatchedServer:
                     f"request needs up to {worst} KV pages but the pool "
                     f"only has {self.manager.capacity}")
         self._uid += 1
-        req = Request(self._uid, prompt, max_new_tokens=max_new_tokens)
+        req = Request(self._uid, prompt, max_new_tokens=max_new_tokens,
+                      t_submit=time.perf_counter())
         req.submitted_block = self.stats["blocks"]
         req.deadline_blocks = deadline_blocks
         overload = self._admission_gate(req, worst)
@@ -1110,7 +1129,8 @@ class BatchedServer:
         self._sched_counter += 1
         self._last_sched[slot] = self._sched_counter
         req.admitted_at_block = self.stats["blocks"]
-        first = int(jax.device_get(nxt)[0, 0])
+        with TraceAnnotation("serve.sync"):
+            first = int(jax.device_get(nxt)[0, 0])
         req.output.append(first)
         if self.record_logits:
             req.logits.append(np.asarray(jax.device_get(logits))[0, -1])
@@ -1154,7 +1174,14 @@ class BatchedServer:
                 break
         if self.prefill is not None:
             self._async_admission(finished, allow_preempt)
-            return
+        else:
+            self._admit_backlog(finished, allow_preempt)
+        self._note_blocked()
+
+    def _admit_backlog(self, finished: list[Request],
+                       allow_preempt: bool) -> None:
+        """Monolithic admission: prefill backlogged requests into free
+        slots in arrival order, behind the page gate."""
         while True:
             free = self._free_slots()
             if not free:
@@ -1174,8 +1201,10 @@ class BatchedServer:
                     return
             self._backlog.popleft()
             self._pending_remove(req)
+            t = time.perf_counter()
             try:
-                done_now = self._admit(req, free[0])
+                with TraceAnnotation("serve.admit", uid=req.uid):
+                    done_now = self._admit(req, free[0])
             except MemoryError:
                 # physically out of pages (injected exhaustion window):
                 # roll back the reservation and keep FIFO order
@@ -1184,8 +1213,30 @@ class BatchedServer:
                 self._backlog.appendleft(req)
                 self._pending_add(req)
                 return
+            if req.t_admit is None:
+                req.t_admit = t
             if done_now:
                 finished.append(req)      # done at admission: slot stays free
+
+    def _note_blocked(self) -> None:
+        """Close the interval since the previous admission check, adding
+        its host seconds to the cause found then, and open the next: the
+        backlog head waits on pages (a slot — the prefill engine's, under
+        ``prefill_async`` — is free but the head's worst case does not
+        fit the unreserved pool) or on slots (none is free).  An empty
+        backlog waits on nothing."""
+        now = time.perf_counter()
+        if self._blocked is not None:
+            self.stats[f"queue_blocked_{self._blocked}_s"] += (
+                now - self._blocked_since)
+        cause = None
+        if self._backlog:
+            if self.prefill is not None:
+                free = len(self.prefill.inflight) < self.prefill.max_inflight
+            else:
+                free = bool(self._free_slots())
+            cause = "pages" if free else "slots"
+        self._blocked, self._blocked_since = cause, now
 
     # ----- disaggregated admission (async prefill engine) ---------------------
     def _async_admission(self, finished: list[Request],
@@ -1205,7 +1256,10 @@ class BatchedServer:
                    and self._admission_pages_ready(self._backlog[0])):
                 req = self._backlog.popleft()
                 self._pending_remove(req)
-                eng.start(req)
+                if req.t_admit is None:
+                    req.t_admit = time.perf_counter()
+                with TraceAnnotation("serve.admit", uid=req.uid):
+                    eng.start(req)
                 started = True
             if (self._backlog and not started and allow_preempt
                     and not self._admission_pages_ready(self._backlog[0])
@@ -1666,6 +1720,22 @@ class BatchedServer:
                 self._dev(jnp.asarray(d_cols)),
                 self._dev(jnp.asarray(d_pids)))
 
+    def _count_kv_pages(self, advances: dict) -> None:
+        """The paged kernel's grid for the block about to go out, per
+        layer: it visits every (slot, table column) at every step; of
+        those, a slot that advances attends at each step the pages that
+        hold its pooled positions (the kernel's ``seq_lens``, the slot's
+        position counting in-flight blocks; the current token's own key
+        is not pooled)."""
+        page = self.page_size
+        self.stats["kv_pages_visited"] += (self.batch * self._table_w
+                                           * self.block_size)
+        live = 0
+        for i, (_, adv) in advances.items():
+            start = self._slot_pos[i] + self._planned[i] - adv
+            live += sum(-(-(start + k) // page) for k in range(adv))
+        self.stats["kv_pages_live"] += live
+
     def _dispatch_block(self):
         """Dispatch ONE fused decode block without waiting for earlier
         blocks (the donated cache/state buffers chain dispatches in
@@ -1696,6 +1766,7 @@ class BatchedServer:
                 self._pool_fault = True
                 return None
             delta = self._table_delta()
+            self._count_kv_pages(advances)
             self.kv.record()
             self._note_peak()
             with self._mesh_ctx():
@@ -1724,7 +1795,8 @@ class BatchedServer:
         fully overwritten (admission prefill writes whole pages) or
         masked until the new owner actually writes each position."""
         outs, advances = block
-        toks_h, valid_h, poison_h, *logits_h = jax.device_get(outs)
+        with TraceAnnotation("serve.sync"):
+            toks_h, valid_h, poison_h, *logits_h = jax.device_get(outs)
         self.stats["host_syncs"] += 1
         for i, (req, adv) in advances.items():
             if self.slots[i] is req:
@@ -1773,6 +1845,7 @@ class BatchedServer:
             self.kv.record()               # per-tier ledger accounting
         self._cold_park_sweep()            # demote over-age stashes
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.run_once")
     def run_once(self, max_blocks: int | None = None) -> list[Request]:
         """Admit queued requests and serve until every admitted request
         completes; returns the finished ones (shed requests too — check
@@ -1801,13 +1874,17 @@ class BatchedServer:
                 while (len(inflight) < self.max_inflight
                        and self._can_dispatch()
                        and (max_blocks is None or dispatched < max_blocks)):
-                    blk = self._dispatch_block()
+                    index = self.stats["blocks"]
+                    with TraceAnnotation("serve.dispatch", block=index):
+                        blk = self._dispatch_block()
                     if blk is None:      # pool fault latched: drain first
                         break
                     dispatched += 1
-                    inflight.append(blk)
+                    inflight.append((index, blk))
             if inflight:
-                self._harvest(inflight.popleft(), finished)
+                index, blk = inflight.popleft()
+                with TraceAnnotation("serve.harvest", block=index):
+                    self._harvest(blk, finished)
                 self._admit_from_queue(finished,
                                        allow_preempt=not inflight)
                 self._maybe_audit()
