@@ -16,7 +16,9 @@ Two halves of the FengHuang block-pool KV cache:
 
 Page 0 is the reserved **null page**: table padding and the write slots
 of idle/finished sequences point at it, so garbage reads are masked by
-``seq_lens`` and garbage writes land where no sequence ever looks.
+``seq_lens`` and garbage writes land where no sequence ever looks.  The
+kernel takes a row whose first column is the null page for a dead slot
+and fetches none of its pages (``kernel.pages_fetched``).
 """
 from __future__ import annotations
 

@@ -474,8 +474,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     cur_pos: (B,) — pooled positions < cur_pos are live, the current
     token arrives via ``extra_kv``.  ``k_scales``/``v_scales``
     ((P, Hkv, page), quantized pools only) dequantize inline: the kernel
-    rescales each page tile inside its online-softmax loop; the fallback
-    rescales the gathered fp32 view — full-precision KV never
+    rescales each block's score tile inside its online-softmax loop; the
+    fallback rescales the gathered fp32 view — full-precision KV never
     materializes pool-wide either way.  Routed once per backend: the
     Pallas ``paged_attention`` kernel on TPU (scalar-prefetched page
     tables, one call per head shard under a mesh), the gather +

@@ -17,10 +17,13 @@ of a dense ``(L, B, Hkv, max_seq, hd)`` slab: fixed-size pages allocated
 on demand at block boundaries by a host-side :class:`BlockManager` and
 reclaimed on EOS/eviction, with prefill writing straight into freshly
 allocated pages and decode attention reading only the pages each slot's
-table maps (the Pallas ``paged_attention`` kernel on TPU, its gather
-oracle elsewhere).  KV memory then scales with live tokens rather than
-``batch × max_seq``, and per-step attention cost with the actual
-sequence length — while emitting bit-identical tokens to the dense path.
+table maps.  On TPU the Pallas ``paged_attention`` kernel fetches and
+attends only a live slot's pages below its length, in blocks of up to 8
+pages, and nothing for a slot whose row is nulled, so its per-step cost
+follows the live KV rather than ``batch × table width``; elsewhere the
+gather oracle reads the whole table.  KV memory scales with live tokens
+rather than ``batch × max_seq`` — while emitting bit-identical tokens to
+the dense path.
 
 Three structures close the paged-vs-dense throughput gap:
 
@@ -72,6 +75,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro import memory
+from repro.kernels.paged_attention.kernel import pages_fetched
 from repro.memory import MemoryOrchestrator
 from repro.memory import tiers as memtiers
 from repro.memory.swap import PageSwapper, SwapHandle
@@ -458,8 +462,8 @@ class BatchedServer:
                       "poison_sheds": 0, "engine_crashes": 0,
                       "lease_reclaims": 0, "crash_requeues": 0,
                       "e2e_p50_blocks": 0.0, "e2e_p99_blocks": 0.0,
-                      # the paged kernel's grid steps per layer, and
-                      # those on a page holding an attended position
+                      # table columns the paged kernel fetches per
+                      # layer, and those holding an attended position
                       "kv_pages_visited": 0, "kv_pages_live": 0,
                       # host seconds the backlog head waited, by cause
                       "queue_blocked_pages_s": 0.0,
@@ -1721,20 +1725,26 @@ class BatchedServer:
                 self._dev(jnp.asarray(d_pids)))
 
     def _count_kv_pages(self, advances: dict) -> None:
-        """The paged kernel's grid for the block about to go out, per
-        layer: it visits every (slot, table column) at every step; of
-        those, a slot that advances attends at each step the pages that
-        hold its pooled positions (the kernel's ``seq_lens``, the slot's
-        position counting in-flight blocks; the current token's own key
-        is not pooled)."""
+        """The paged kernel's work for the block about to go out, per
+        layer.  At each step it fetches, for every slot, the table
+        columns :func:`pages_fetched` gives for the slot's row in the
+        dispatched table and its ``seq_lens`` — the slot's position
+        counting in-flight blocks, frozen once the slot stops — and
+        nothing for a nulled row.  Of those, a slot that advances
+        attends at each step the pages that hold its pooled positions
+        (the current token's own key is not pooled)."""
         page = self.page_size
-        self.stats["kv_pages_visited"] += (self.batch * self._table_w
-                                           * self.block_size)
-        live = 0
-        for i, (_, adv) in advances.items():
-            start = self._slot_pos[i] + self._planned[i] - adv
-            live += sum(-(-(start + k) // page) for k in range(adv))
-        self.stats["kv_pages_live"] += live
+        adv = np.zeros(self.batch, np.int64)
+        for i, (_, n) in advances.items():
+            adv[i] = n
+        start = (np.asarray(self._slot_pos) + np.asarray(self._planned)
+                 - adv)
+        k = np.arange(self.block_size)
+        lens = start[:, None] + np.minimum(k, adv[:, None])   # (B, block)
+        self.stats["kv_pages_visited"] += int(pages_fetched(
+            self._mirror[:, :1], lens, page, self._table_w, xp=np).sum())
+        attended = -(-lens // page) * (k < adv[:, None])
+        self.stats["kv_pages_live"] += int(attended.sum())
 
     def _dispatch_block(self):
         """Dispatch ONE fused decode block without waiting for earlier

@@ -110,6 +110,56 @@ def test_paged_kernel_seq_len_zero_with_self_column():
         atol=2e-5, rtol=2e-5)
 
 
+def _live_case(b, hkv, g, width, page, d=32):
+    kp, vp, table = _pool(b, width, page, hkv, d)
+    q = jnp.asarray(RNG.randn(b, hkv, g, d), jnp.float32) * 0.3
+    k0 = jnp.asarray(RNG.randn(b, hkv, d), jnp.float32) * 0.3
+    v0 = jnp.asarray(RNG.randn(b, hkv, d), jnp.float32)
+    return q, kp, vp, table, (k0, v0)
+
+
+@pytest.mark.parametrize("width,lens", [
+    (1, (16, 5)),        # tables narrower than a block of 128 positions
+    (2, (17, 32)),
+    (4, (0, 64)),        # seq_len 0 beside a full table
+    (16, (128, 129)),    # on a block boundary (8 pages of 16), one past
+    (16, (256, 1)),      # the whole table, and a single position
+])
+@pytest.mark.parametrize("hkv", [1, 2])      # 2: a tp=4 head shard
+def test_paged_kernel_live_blocks(width, lens, hkv):
+    """The kernel fetches whole blocks of live pages (16-position pages,
+    blocks of up to 8) and masks past ``seq_lens``: it matches the gather
+    oracle at block edges and for tables narrower than a block."""
+    q, kp, vp, table, kv0 = _live_case(2, hkv, 3, width, 16)
+    seq_lens = jnp.asarray(lens, jnp.int32)
+    out = pa.attend(q, kp, vp, table, seq_lens, kv0, interpret=True)
+    ref = pa.attend_ref(q, kp, vp, table, seq_lens, kv0)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("frozen", [5, 100])
+def test_paged_kernel_dead_slot_reads_no_page(frozen):
+    """An evicted slot keeps its frozen length while its row is nulled:
+    the kernel reads none of its pages — the null page, poisoned with
+    NaN here, never reaches the output — and it attends its current
+    token alone; the live slot beside it is unaffected."""
+    b, hkv, g, d, width, page = 2, 2, 3, 32, 8, 16
+    q, kp, vp, table, (k0, v0) = _live_case(b, hkv, g, width, page, d)
+    kp, vp = kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan)
+    table = table.at[1].set(0)
+    seq_lens = jnp.asarray([40, frozen], jnp.int32)
+    out = np.asarray(pa.attend(q, kp, vp, table, seq_lens, (k0, v0),
+                               interpret=True))
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(
+        out[1], np.broadcast_to(np.asarray(v0)[1, :, None, :],
+                                (hkv, g, d)), atol=2e-5, rtol=2e-5)
+    ref = pa.attend_ref(q, kp, vp, table, seq_lens, (k0, v0))
+    np.testing.assert_allclose(out[0], np.asarray(ref)[0],
+                               atol=2e-5, rtol=2e-5)
+
+
 def test_paged_decode_attention_backend_routing(monkeypatch):
     """Kernel (interpret) and gather fallback agree through the layer-level
     entry point, q in (B, 1, Hq, hd) layout."""

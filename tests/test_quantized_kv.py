@@ -219,6 +219,33 @@ def test_kernel_matches_ref_with_scales(name, dt, qmax):
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("lens", [(128, 129), (37, 0)])
+@pytest.mark.parametrize("name,dt,qmax", KV_DTYPES)
+def test_kernel_blocks_of_pages_with_scales(name, dt, qmax, lens):
+    """Blocks of 8 pages of 16 positions: each page's scales are moved to
+    its own lanes of the block's score tile.  The kernel matches the
+    gather oracle at a block edge, one past it, and for an empty slot."""
+    key = jax.random.PRNGKey(1)
+    b, hkv, g, hd, n_pages, page, pool = 2, 2, 5, 32, 16, 16, 40
+    ks = jax.random.split(key, 6)
+    q = jax.random.normal(ks[0], (b, hkv, g, hd), jnp.float32)
+    kf = jax.random.normal(ks[1], (pool, hkv, page, hd), jnp.float32)
+    vf = jax.random.normal(ks[2], (pool, hkv, page, hd), jnp.float32)
+    k_pages, k_scales = L.kv_pool_quantize(kf, dt, qmax)
+    v_pages, v_scales = L.kv_pool_quantize(vf, dt, qmax)
+    table = jax.random.randint(ks[3], (b, n_pages), 1, pool, jnp.int32)
+    kv0 = (jax.random.normal(ks[4], (b, hkv, hd), jnp.float32),
+           jax.random.normal(ks[5], (b, hkv, hd), jnp.float32))
+    seq_lens = jnp.asarray(lens, jnp.int32)
+    ref = attend_ref(q, k_pages, v_pages, table, seq_lens, extra_kv=kv0,
+                     k_scales=k_scales, v_scales=v_scales)
+    out = paged_attention(q, k_pages, v_pages, table, seq_lens,
+                          extra_kv=kv0, k_scales=k_scales,
+                          v_scales=v_scales, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
 def test_scales_must_come_in_pairs():
     q = jnp.zeros((1, 1, 1, 8))
     kp = jnp.zeros((2, 4, 1, 8), jnp.int8)

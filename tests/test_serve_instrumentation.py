@@ -100,7 +100,12 @@ def test_kv_page_counters_match_a_hand_count(tiny_model, pipeline):
     runs two blocks; B (3 tokens) advances 2 steps of the first block
     and finishes there.  The table is 4 pages wide in both blocks.
 
-    visited: 2 blocks x 2 slots x 4 columns x 4 steps = 64.
+    visited: the kernel's blocks span 4 pages, the table's width, so a
+    slot whose row is mapped fetches 4 columns at each step.  Block 1:
+    A and B, 2 x 4 steps x 4 = 32 (B keeps its row and frozen length
+    after it stops).  Block 2: A 16, and B 16 more when pipelined, since
+    block 2 then goes out before block 1's harvest frees B's row: 64
+    pipelined, 48 not.
     live, pages holding the pooled positions each step attends:
     block 1, A at 8..11 -> 2+3+3+3 = 11, B at 8..9 -> 2+3 = 5;
     block 2, A at 12..15 -> 3+4+4+4 = 15; in all 31."""
@@ -109,8 +114,44 @@ def test_kv_page_counters_match_a_hand_count(tiny_model, pipeline):
     _drive(srv, [a, b])
     assert (len(a.output), len(b.output)) == (9, 3)
     assert srv.stats["blocks"] == 2
-    assert srv.stats["kv_pages_visited"] == 64
+    assert srv.stats["kv_pages_visited"] == (64 if pipeline else 48)
     assert srv.stats["kv_pages_live"] == 31
+
+
+@pytest.mark.parametrize("pipeline", [True, False])
+def test_kv_pages_visited_follows_the_kernels_block_rule(
+        tiny_model, monkeypatch, pipeline):
+    """Every call of the paged kernel in a served run records the table
+    and lengths it was given; the server's count equals what the
+    kernel's own rule fetches for them, per layer.  Slots finish at
+    different steps and one slot is freed while others run, so the
+    tables carry nulled rows with frozen lengths, which fetch nothing."""
+    from repro.kernels.paged_attention import kernel as pk
+    from repro.kernels.paged_attention import ops as paged_ops
+    calls = []
+
+    def record(table, lens):
+        calls.append((np.asarray(table), np.asarray(lens)))
+
+    def paged_attention(q, k_pages, v_pages, page_table, seq_lens, **kw):
+        jax.debug.callback(record, page_table, seq_lens)
+        return paged_ops.paged_attention_ref(q, k_pages, v_pages,
+                                             page_table, seq_lens, **kw)
+
+    monkeypatch.setattr(paged_ops, "use_pallas_kernel", lambda: True)
+    monkeypatch.setattr(paged_ops, "paged_attention", paged_attention)
+    srv = _server(tiny_model, batch_size=3, pipeline=pipeline)
+    reqs = _submit(srv, [(8, 13), (4, 3), (12, 6), (4, 9)])
+    _drive(srv, reqs)
+    layers = tiny_model[0].cfg.num_layers
+    assert len(calls) == srv.stats["steps"] * layers
+    fetched = sum(
+        int(pk.pages_fetched(table[:, 0], lens, PAGE, table.shape[1],
+                             xp=np).sum()) for table, lens in calls)
+    dead = [(table[:, 0] == 0) & (lens > 0) for table, lens in calls]
+    assert any(d.any() for d in dead)     # a nulled row kept its length
+    assert srv.stats["kv_pages_visited"] == fetched // layers
+    assert 0 < srv.stats["kv_pages_live"] <= srv.stats["kv_pages_visited"]
 
 
 # ---------------------------------------------------------------------------
